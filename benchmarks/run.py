@@ -25,6 +25,9 @@ def main() -> None:
                     help="output path for machine-readable records "
                          "('' disables)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     only = None if args.only == "all" else set(args.only.split(","))
 
     from . import (

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.cg import cg_full_tensor_product, gaunt_einsum_reference
 from repro.core.conv import EquivariantConv
 from repro.core.gaunt import GauntTensorProduct
@@ -18,6 +19,7 @@ from repro.kernels.ops import gaunt_tp_fused_xla
 
 
 def main():
+    enable_compile_cache()
     L = 4
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(8, num_coeffs(L))), jnp.float32)

@@ -8,6 +8,7 @@ import time
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.config import get_config
 from repro.models import build_model
 from repro.serve import Request, ServeEngine
@@ -21,6 +22,7 @@ def main():
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     model = build_model(cfg)

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.config import TrainConfig
 from repro.configs.gaunt_ff import gaunt_mace_ff
 from repro.data import lj_dataset
@@ -46,6 +47,7 @@ def main():
     ap.add_argument("--channels", type=int, default=16)
     ap.add_argument("--L", type=int, default=2)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = dataclasses.replace(gaunt_mace_ff, channels=args.channels, L=args.L,
                               L_edge=2, n_layers=1, nu=2)

@@ -9,6 +9,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.config import TrainConfig, get_config
 from repro.data import LMTokenPipeline
 from repro.models import build_model
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--ckpt", default="/tmp/lm_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced(
         d_model=args.dim, n_layers=args.layers, n_heads=max(4, args.dim // 64),

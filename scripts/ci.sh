@@ -245,7 +245,7 @@ for r in recs:
             fail.append(f"{r['name']}: autotuner kept bfloat16 but it LOST "
                         f"to its f32 sibling (x{s} < {BF16_FLOOR})")
 
-# guard 5 — persistent autotune: the warm subprocess in the cold-vs-warm
+# guard 5 — persistent autotune: the warm engine in the cold-vs-warm
 # record must have performed ZERO timing runs and selected identically to
 # the cold one — a single warm timing run means the persisted table failed
 # to cover the workload (broken serialization, fingerprint drift, or a
@@ -254,11 +254,11 @@ for r in recs:
     if not r["name"].startswith("engine_autotune_cache"):
         continue
     if r.get("warm_timing_runs", 0) != 0:
-        fail.append(f"{r['name']}: warm process ran "
+        fail.append(f"{r['name']}: warm engine ran "
                     f"{r['warm_timing_runs']} timing runs (must be 0 — the "
                     f"persisted cache did not cover the workload)")
     if not r.get("picks_match", False):
-        fail.append(f"{r['name']}: warm process selected differently from "
+        fail.append(f"{r['name']}: warm engine selected differently from "
                     f"the cold one (persisted table is not faithful)")
 
 # guard 6 — grid-resident gates (DESIGN.md §6.5): exactness first — the

@@ -381,12 +381,19 @@ class ShardSpec:
     mode: str = "constraint"
 
     def resolve(self):
-        """-> (mesh, dp_axes) or (None, ()) when no mesh is available."""
+        """-> (mesh, dp_axes) or (None, ()) when no mesh is available.
+
+        The mesh comes back with every axis Auto: the row layout is imposed
+        with ``with_sharding_constraint``/``shard_map`` specs, which JAX only
+        accepts on Auto axes, and ``jax.make_mesh`` builds Explicit axes by
+        default."""
         from repro.distributed import sharding as _sh  # lazy: keep core light
 
         mesh = self.mesh if self.mesh is not None else _sh.get_activation_mesh()
         if mesh is None:
             return None, ()
+        mesh = mesh.update(
+            axis_types=(jax.sharding.AxisType.Auto,) * len(mesh.axis_names))
         axes = _sh.dp_axes(mesh, tuple(self.axes))
         return mesh, axes
 
@@ -652,15 +659,17 @@ def _shard_rows(run: Callable, mesh, dp: tuple, mode: str) -> Callable:
 
         return sharded
     if mode == "shard_map":
-        from jax.experimental.shard_map import shard_map
 
         def sharded(*args):
             in_specs = jax.tree.map(lambda a: row_pspec(jnp.ndim(a), dp), args)
             out_sds = jax.eval_shape(run, *args)
             out_specs = jax.tree.map(
                 lambda s: row_pspec(len(s.shape), dp), out_sds)
-            return shard_map(run, mesh=mesh, in_specs=tuple(in_specs),
-                             out_specs=out_specs)(*args)
+            # row-parallel bodies hold no collectives, so there is no
+            # replication to check; with the check on, the grad of the
+            # complex grid combine fails its cotangent type test
+            return jax.shard_map(run, mesh=mesh, in_specs=tuple(in_specs),
+                                 out_specs=out_specs, check_vma=False)(*args)
 
         return sharded
     raise ValueError(f"unknown shard mode {mode!r} "
@@ -1775,6 +1784,11 @@ class GauntEngine:
         # keep this at 0 — the warm-start acceptance proof and the CLI's
         # --verify-warm both read it.
         self.timing_runs = 0
+        # candidates that RAISED while being timed (not ones that lost on
+        # time): one dict per failure with the site, key, candidate and
+        # error, so a backend the device's compiler refuses is visible
+        # instead of silently dropping out of the race
+        self.autotune_failures: list[dict] = []
 
     # -- persistent autotune cache -----------------------------------------
 
@@ -2258,7 +2272,8 @@ class GauntEngine:
                     fn()
                     ts.append(time.perf_counter() - t0)
                 t = sorted(ts)[1]
-            except Exception:  # noqa: BLE001 — a broken candidate just loses
+            except Exception as e:  # noqa: BLE001 — recorded, then loses
+                self._record_failure("chain", key, name, e)
                 continue
             if t < best_t:
                 best_name, best_t = name, t
@@ -2424,7 +2439,8 @@ class GauntEngine:
                     jax.block_until_ready(_gate_sh(gp, cps.apply_jit(xs)))
 
             tg, tsh = _time(grid_fn), _time(sh_fn)
-        except Exception:  # noqa: BLE001 — a failed measurement means 'sh'
+        except Exception as e:  # noqa: BLE001 — recorded; failure means 'sh'
+            self._record_failure("gate", key, "grid-vs-sh", e)
             return "sh"
         winner = "grid" if tg < tsh else "sh"
         self._measured[key] = winner
@@ -2553,8 +2569,15 @@ class GauntEngine:
         reset_calibration()
         self._cache_loaded = False
         self.timing_runs = 0
+        self.autotune_failures.clear()
 
     # -- measured autotune -------------------------------------------------
+
+    def _record_failure(self, site: str, key: PlanKey, candidate: str,
+                        err: Exception) -> None:
+        self.autotune_failures.append({
+            "site": site, "key": repr(key), "candidate": candidate,
+            "error": f"{type(err).__name__}: {err}"})
 
     def _measure(self, key: PlanKey,
                  eligible: list[Backend]) -> tuple[str, float | None]:
@@ -2579,7 +2602,8 @@ class GauntEngine:
                     jax.block_until_ready(fn(*args))
                     ts.append(time.perf_counter() - t0)
                 t = sorted(ts)[1]
-            except Exception:  # noqa: BLE001 — a broken backend just loses
+            except Exception as e:  # noqa: BLE001 — recorded, then loses
+                self._record_failure("plan", key, spec.name, e)
                 continue
             if t < best_t:
                 best_name, best_t = spec.name, t
@@ -2589,10 +2613,9 @@ class GauntEngine:
 
 
 def _trace_clean() -> bool:
-    try:
-        return jax.core.trace_state_clean()
-    except Exception:  # noqa: BLE001 — jax internals moved; assume clean
-        return True
+    """True outside every JAX transformation (jit, grad, vmap tracing) —
+    the only place a timing measures the device."""
+    return jax.core.trace_ctx.is_top_level()
 
 
 def _synthetic_inputs(key: PlanKey):

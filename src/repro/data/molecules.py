@@ -41,7 +41,10 @@ def lj_dataset(n_samples: int, n_atoms: int = 8, n_species: int = 4, seed: int =
     pos = np.empty((n_samples, n_atoms, 3))
     E = np.empty(n_samples)
     F = np.empty((n_samples, n_atoms, 3))
-    grid = np.stack(np.meshgrid(*[np.arange(2)] * 3, indexing="ij"), -1).reshape(-1, 3)
+    # the smallest cubic lattice with a site per atom (2x2x2 up to 8 atoms)
+    side = max(2, int(np.ceil(n_atoms ** (1 / 3) - 1e-9)))
+    grid = np.stack(np.meshgrid(*[np.arange(side)] * 3, indexing="ij"),
+                    -1).reshape(-1, 3)
     for s in range(n_samples):
         # jittered lattice keeps pairs off the singular core; resample any
         # configuration with pathological forces

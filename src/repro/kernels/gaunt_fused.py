@@ -31,10 +31,10 @@ multiplies the grid-evaluation matrix (`constants.chain_sample_grid`)
 instead of the SH sampling matrix — same kernel, no sh_to_fourier, and a
 'grid' exit returns the resident half product grid (`chain_project_grid`).
 
-The chain kernel carries a custom VJP (the collocation matmuls are their own
-adjoints: dV_i = (dout @ P^T) * prod_{j!=i} V_j, dx_i = dV_i @ T_i^T, run as
-plain jnp), so chain plans on the kernel backend support grad — unlike the
-historical pairwise `fused_pallas` backend.
+The chain kernel carries a custom JVP (the collocation is multilinear in its
+operands: dout = (sum_i (dx_i @ T_i) * prod_{j!=i} V_j) @ P, run as plain
+jnp), so chain plans on the kernel backend support grad, and grad of grad —
+unlike the historical pairwise `fused_pallas` backend.
 
 Mixed precision (DESIGN.md §3.6): every runner takes a *storage* dtype
 ('float32' | 'bfloat16' | 'float64') governing operand and sampling-matrix
@@ -68,12 +68,16 @@ __all__ = [
 
 
 # ticked once per wrapper call (eager) or trace (jit) — the proof counters
-# behind "a >= 3-operand chain runs as ONE pallas_call"
-_STATS = {"pairwise_pallas_calls": 0, "chain_pallas_calls": 0}
+# behind "a >= 3-operand chain runs as ONE pallas_call"; interpret_calls
+# counts the calls of either kernel that ran in interpret mode (any call
+# off the TPU), so a chip run can prove the compiled kernel ran
+_STATS = {"pairwise_pallas_calls": 0, "chain_pallas_calls": 0,
+          "interpret_calls": 0}
 
 
 def kernel_stats() -> dict:
-    """{'pairwise_pallas_calls': n, 'chain_pallas_calls': m} since reset."""
+    """{'pairwise_pallas_calls', 'chain_pallas_calls', 'interpret_calls'}
+    counts since reset."""
     return dict(_STATS)
 
 
@@ -170,13 +174,17 @@ def _pad_axis(a: np.ndarray, axis: int, to: int) -> np.ndarray:
 def _chain_runner(Ls: tuple, Lout: int, entries: tuple, out_entry: str,
                   block_b: int, block_g: int, interpret: bool, sdt: str,
                   gated: bool = False):
-    """A cached, custom-VJP'd row-level chain runner for one static config.
+    """A cached, custom-JVP'd row-level chain runner for one static config.
 
     Takes the tuple of row-flattened operands ([Bp, d_i], already padded to a
     multiple of ``block_b``) and returns [Bp, dout] — ONE `pallas_call`.
-    The VJP reuses the same collocation matrices in plain jnp (dV_i =
-    (dout @ P^T) * prod_{j != i} V_j; dx_i = dV_i @ T_i^T), so the kernel
-    backend is grad-capable while the forward stays a single kernel.
+    The JVP reuses the same collocation matrices in plain jnp (dout =
+    (sum_i (dx_i @ T_i) * prod_{j != i} V_j) @ P, linear in the tangents), so
+    JAX transposes it for reverse mode and differentiates it again for
+    higher orders — force-matched training takes the gradient of forces,
+    which are themselves a gradient — while every primal evaluation stays a
+    single kernel.  (Pallas has no derivative rule of its own for this
+    kernel: its grid program ids cannot be differentiated.)
 
     ``sdt`` is the storage dtype: operands and sampling matrices T_i live at
     ``sdt``, every dot accumulates at the >= f32 accumulation dtype, and the
@@ -185,9 +193,8 @@ def _chain_runner(Ls: tuple, Lout: int, entries: tuple, out_entry: str,
     ``gated`` runners take two extra row-scalar arrays ([Bp, 1], at the
     accumulation dtype): ``run(arrs, gs, gb)`` applies ``v <- v*gs + gb`` to
     the product values between the n-way multiply and the projection —
-    still ONE `pallas_call`.  The VJP extends accordingly: with V the
-    pre-gate product grid, dgs = rowsum(U*V), dgb = rowsum(U), and each
-    operand gradient picks up the gs scale (U = dout @ P^T).
+    still ONE `pallas_call`.  The JVP extends accordingly: with V the
+    pre-gate product grid, the tangent is (dV*gs + V*dgs + dgb) @ P.
     """
     from repro.core.constants import chain_matrices
 
@@ -222,56 +229,51 @@ def _chain_runner(Ls: tuple, Lout: int, entries: tuple, out_entry: str,
             interpret=interpret,
         )(*arrs, *(jnp.asarray(T) for T in Ts), jnp.asarray(P), *gate_arrs)
 
-    def _bwd_core(arrs, gs, dout_bar):
+    def _tangent(arrs, darrs, gs=None, dgs=None, dgb=None):
         # same storage discipline as the forward: operands and T stay at
         # ``sdt`` into the MXU, accumulation at acc_dt via preferred dtype
         Tj = [jnp.asarray(T) for T in Ts]
         Vs = [jnp.dot(a, T, preferred_element_type=acc_dt)
               for a, T in zip(arrs, Tj)]
-        U = dout_bar.astype(acc_dt) @ jnp.asarray(P).T
-        Ug = U if gs is None else U * gs.astype(acc_dt)
-        grads = []
+        dVs = [jnp.dot(da.astype(a.dtype), T, preferred_element_type=acc_dt)
+               for a, da, T in zip(arrs, darrs, Tj)]
+        dV = 0.0
         for i in range(n):
-            dV = Ug
+            term = dVs[i]
             for j in range(n):
                 if j != i:
-                    dV = dV * Vs[j]
-            grads.append((dV @ Tj[i].T.astype(acc_dt)).astype(arrs[i].dtype))
-        return tuple(grads), Vs, U
-
-    if gated:
-
-        @jax.custom_vjp
-        def run(arrs, gs, gb):
-            return _call(arrs, (gs, gb))
-
-        def fwd(arrs, gs, gb):
-            return _call(arrs, (gs, gb)), (arrs, gs, gb)
-
-        def bwd(res, dout_bar):
-            arrs, gs, gb = res
-            grads, Vs, U = _bwd_core(arrs, gs, dout_bar)
+                    term = term * Vs[j]
+            dV = dV + term
+        if gs is not None:
             V = Vs[0]
             for Vj in Vs[1:]:
                 V = V * Vj
-            dgs = jnp.sum(U * V, axis=-1, keepdims=True).astype(gs.dtype)
-            dgb = jnp.sum(U, axis=-1, keepdims=True).astype(gb.dtype)
-            return grads, dgs, dgb
+            dV = dV * gs.astype(acc_dt) + V * dgs.astype(acc_dt) \
+                + dgb.astype(acc_dt)
+        return dV @ jnp.asarray(P)
+
+    if gated:
+
+        @jax.custom_jvp
+        def run(arrs, gs, gb):
+            return _call(arrs, (gs, gb))
+
+        @run.defjvp
+        def _jvp(primals, tangents):
+            (arrs, gs, gb), (darrs, dgs, dgb) = primals, tangents
+            return run(arrs, gs, gb), _tangent(arrs, darrs, gs, dgs, dgb)
 
     else:
 
-        @jax.custom_vjp
+        @jax.custom_jvp
         def run(arrs):
             return _call(arrs)
 
-        def fwd(arrs):
-            return _call(arrs), arrs
+        @run.defjvp
+        def _jvp(primals, tangents):
+            (arrs,), (darrs,) = primals, tangents
+            return run(arrs), _tangent(arrs, darrs)
 
-        def bwd(arrs, dout_bar):
-            grads, _, _ = _bwd_core(arrs, None, dout_bar)
-            return (grads,)
-
-    run.defvjp(fwd, bwd)
     return run, dout
 
 
@@ -338,8 +340,8 @@ def gaunt_chain_fused_pallas(
               inside the same single `pallas_call` (DESIGN.md §6.5).
 
     float64 storage exists only under x64 and is interpret-only (TPUs have
-    no f64).  Differentiable via the collocation VJP (extended with
-    dgs/dgb when gated).
+    no f64).  Differentiable to any order via the collocation JVP (extended
+    with dgs/dgb when gated).
     """
     Ls = tuple(int(L) for L in Ls)
     Lout = sum(Ls) if Lout is None else int(Lout)
@@ -371,6 +373,7 @@ def gaunt_chain_fused_pallas(
     run, dout = _chain_runner(Ls, Lout, entries, out_entry, block_b, block_g,
                               bool(interpret), sdt, gate is not None)
     _STATS["chain_pallas_calls"] += 1
+    _STATS["interpret_calls"] += bool(interpret)
     Bp = -(-B // block_b) * block_b
     st_dt = jnp.dtype(sdt)
     flat = [jnp.zeros((Bp, a.shape[-1]), st_dt).at[:B].set(a.astype(st_dt))
@@ -473,6 +476,7 @@ def gaunt_fused_pallas(
         interpret = jax.default_backend() != "tpu"
     G = T1.shape[1]
     _STATS["pairwise_pallas_calls"] += 1
+    _STATS["interpret_calls"] += bool(interpret)
     out = pl.pallas_call(
         _kernel,
         grid=(Bp // block_b,),

@@ -19,9 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 exposes the TPU compiler params as TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["mamba2_ssd_chunked", "mamba2_ssd_pallas"]
 
 
@@ -140,7 +137,7 @@ def mamba2_ssd_pallas(x, dt, A, B, C, D, chunk: int = 64, interpret: bool | None
         out_specs=pl.BlockSpec((1, Ck, P), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((R, T, P), jnp.float32),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,

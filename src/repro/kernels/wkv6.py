@@ -27,9 +27,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 exposes the TPU compiler params as TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["wkv6_chunked", "wkv6_pallas"]
 
 
@@ -149,7 +146,7 @@ def wkv6_pallas(r, k, v, w, u, chunk: int = 64, interpret: bool | None = None):
         out_specs=pl.BlockSpec((1, C, V), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, T, V), jnp.float32),
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,

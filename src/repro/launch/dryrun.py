@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.config import SHAPES, TrainConfig, get_config
 from repro.configs import ALL_LM_ARCHS, SUBQUADRATIC
 from repro.distributed.sharding import batch_shardings, cache_shardings, param_shardings
@@ -151,8 +152,6 @@ def dryrun_cell(arch: str, shape_name: str, multi_pod: bool, tiny: bool = False,
 
     mem = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax < 0.5 returns a per-device list
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     coll = parse_collectives(hlo, body_multipliers_for(cfg))
     n_dev = int(np.prod(mesh.devices.shape))
@@ -193,6 +192,7 @@ def main():
                     help="sharding layout variant (default|dp_heavy|moe_expert_tp)")
     ap.add_argument("--resume", action="store_true", help="skip cells already in --out")
     args = ap.parse_args()
+    enable_compile_cache()
 
     archs = ALL_LM_ARCHS if args.arch == "all" else args.arch.split(",")
     shapes = list(SHAPES) if args.shape == "all" else args.shape.split(",")

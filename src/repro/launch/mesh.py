@@ -7,13 +7,10 @@ import jax
 __all__ = ["make_production_mesh", "make_host_mesh"]
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """jax >= 0.5 wants explicit AxisType; older jax has neither the enum nor
-    the kwarg — omit it there (Auto is the default behavior anyway)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto(n_axes: int) -> tuple:
+    """Auto axes: the partitioner places what the sharding rules leave open
+    (``jax.make_mesh`` defaults to Explicit axes)."""
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -21,7 +18,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     for the 512-chip two-pod dry run."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -29,4 +26,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // data))
-    return jax.make_mesh((data, model), ("data", "model"), **_axis_type_kwargs(2))
+    return jax.make_mesh((data, model), ("data", "model"), axis_types=_auto(2))

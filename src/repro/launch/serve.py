@@ -25,6 +25,9 @@ def main():
     ap.add_argument("--max-len", type=int, default=256)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

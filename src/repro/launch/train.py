@@ -22,11 +22,13 @@ import subprocess
 import sys
 import time
 
-import jax
 import numpy as np
 
 
 def run_once(args) -> int:
+    import jax
+
+    from repro.compile_cache import enable_compile_cache
     from repro.config import TrainConfig, get_config
     from repro.data import LMTokenPipeline
     from repro.distributed.sharding import batch_shardings, param_shardings
@@ -34,6 +36,7 @@ def run_once(args) -> int:
     from repro.models import build_model
     from repro.train import train_loop
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -138,6 +138,31 @@ def test_chain_kernel_grad_matches_tree(backend):
                                rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("gated", [False, True])
+def test_chain_kernel_grad_of_grad_matches_xla(gated):
+    """Force-matched training differentiates forces, themselves a gradient:
+    the Pallas chain kernel must take a second derivative (through its
+    custom JVP) and agree with the plain-XLA collocation."""
+    Ls, Lout, B, C = (2, 2, 2), 2, 4, 3
+    x = _rand((B, C, num_coeffs(2)), 390)
+    w = _rand((3,), 391)
+    gp = _gate_params(C, 392) if gated else None
+    plans = [engine.plan_chain(Ls, Lout, backend=b, gate=gated)
+             for b in ("fused_pallas", "fused_xla")]
+
+    def outer(plan):
+        kw = {"gate_params": gp} if gated else {}
+
+        def energy(a, w):
+            return jnp.sum(plan.apply([a * w[i] for i in range(3)], **kw) ** 2)
+
+        return lambda w: jnp.sum(jax.grad(energy)(x, w) ** 2)
+
+    g_p, g_x = (jax.grad(outer(p))(w) for p in plans)
+    np.testing.assert_allclose(np.asarray(g_p), np.asarray(g_x),
+                               rtol=2e-3, atol=2e-3)
+
+
 @pytest.mark.parametrize("backend", ["fused_xla", "fused_pallas"])
 def test_chain_kernel_vmap(backend):
     Ls, Lout = (2, 2, 2), 2
@@ -340,7 +365,7 @@ def test_gated_chain_single_pallas_call():
 
 
 def test_gated_chain_grad_matches_xla():
-    """The extended custom VJP: gradients through the fused gate (wrt both
+    """The extended custom JVP: gradients through the fused gate (wrt both
     an operand and the gate MLP weights) match the XLA reference kernel."""
     Ls, Lout, B, C = (2, 1, 2), 3, 4, 3
     xs = [_rand((B, C, num_coeffs(L)), 370 + i) for i, L in enumerate(Ls)]

@@ -1,5 +1,7 @@
 """The unified Gaunt engine: cross-backend equivalence against the complex128
 numpy oracle, plan/constant caching, capability filtering, and autotune."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -400,3 +402,65 @@ def test_clear_resets_calibration_so_fresh_engines_rank_identically():
         assert engine.GauntEngine().select(k) == pick_fresh
     finally:
         set_calibration(**base)
+
+
+# --------------------------------------------------------------------------
+# measurement guards: no timing inside a trace, no silent candidate failure
+# --------------------------------------------------------------------------
+
+
+def test_trace_clean_is_false_inside_jit():
+    seen = []
+
+    def f(x):
+        seen.append(engine._trace_clean())
+        return x
+
+    jax.jit(f)(1.0)
+    jax.grad(f)(1.0)
+    assert engine._trace_clean()
+    assert seen == [False, False]
+
+
+def test_measure_inside_jit_makes_no_timing_runs():
+    """A measure-mode plan requested inside a jit trace keeps the safe
+    default without timing; the same request made eagerly then measures."""
+    eng = engine.GauntEngine()
+    picks = []
+
+    def f(x):
+        picks.append(eng.plan_chain((1, 1), 2, tune="measure",
+                                    batch_hint=16).backend)
+        picks.append(eng.plan(1, 1, 2, tune="measure", batch_hint=16).backend)
+        return x
+
+    jax.jit(f)(1.0)
+    assert eng.timing_runs == 0
+    assert picks[0] == "tree"
+    eng.plan_chain((1, 1), 2, tune="measure", batch_hint=16)
+    assert eng.timing_runs == 1
+
+
+@pytest.mark.parametrize("site", ["chain", "plan"])
+def test_failing_autotune_candidate_is_recorded(monkeypatch, site):
+    def refuse(*a, **k):
+        raise RuntimeError("refused by the compiler")
+
+    eng = engine.GauntEngine()
+    if site == "chain":
+        monkeypatch.setattr(engine, "_build_chain_fused", refuse)
+        picked = eng.plan_chain((1, 1), 2, tune="measure",
+                                batch_hint=16).backend
+    else:
+        spec = engine._REGISTRY["fused_xla"]
+        monkeypatch.setitem(engine._REGISTRY, "fused_xla",
+                            dataclasses.replace(spec, build=refuse))
+        picked = eng.plan(1, 1, 2, tune="measure", batch_hint=16,
+                          requires_grad=False).backend
+    assert picked != "fused_xla"
+    assert len(eng.autotune_failures) == 1
+    rec = eng.autotune_failures[0]
+    assert rec["site"] == site and rec["candidate"] == "fused_xla"
+    assert "refused by the compiler" in rec["error"]
+    eng.clear()
+    assert eng.autotune_failures == []

@@ -2,6 +2,7 @@
 Product primitive wired through a real training run, the fault-tolerance
 path, and the multi-device dry-run contract (on a small host mesh)."""
 import dataclasses
+import os
 import subprocess
 import sys
 
@@ -75,7 +76,7 @@ def test_dryrun_tiny_cell_subprocess():
         "M.make_production_mesh = lambda multi_pod=False: jax.make_mesh("
         "(2,2,2) if multi_pod else (4,2), ('pod','data','model') if multi_pod"
         " else ('data','model'),"
-        "**M._axis_type_kwargs(3 if multi_pod else 2));"
+        "axis_types=M._auto(3 if multi_pod else 2));"
         # dryrun binds the name at import — patch its reference too
         "D.make_production_mesh = M.make_production_mesh;"
         "r1 = D.dryrun_cell('qwen2-0.5b','train_4k', False, tiny=True);"
@@ -91,6 +92,19 @@ def test_dryrun_tiny_cell_subprocess():
         timeout=900,
     )
     assert "DRYRUN_OK" in out.stdout, out.stderr[-2000:]
+
+
+def test_train_supervisor_never_loads_jax():
+    """The --supervise parent starts the worker that takes the chip, so it
+    must not hold a backend itself: importing the launcher loads no JAX."""
+    code = ("import sys, repro.launch.train;"
+            "assert 'jax' not in sys.modules, 'launcher imported jax';"
+            "print('NO_JAX_OK')")
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        env=dict(os.environ, PYTHONPATH="src"), timeout=300,
+    )
+    assert "NO_JAX_OK" in out.stdout, out.stderr[-2000:]
 
 
 def test_gaunt_primitive_in_training_matches_cg_class():
