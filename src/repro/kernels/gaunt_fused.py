@@ -227,6 +227,7 @@ def _chain_runner(Ls: tuple, Lout: int, entries: tuple, out_entry: str,
             out_specs=pl.BlockSpec((block_b, dout), lambda i, g: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((Bp, dout), acc_dt),
             interpret=interpret,
+            name="gaunt_chain_fused",
         )(*arrs, *(jnp.asarray(T) for T in Ts), jnp.asarray(P), *gate_arrs)
 
     def _tangent(arrs, darrs, gs=None, dgs=None, dgb=None):
@@ -490,5 +491,6 @@ def gaunt_fused_pallas(
         out_specs=pl.BlockSpec((block_b, dout), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, dout), jnp.float32),
         interpret=interpret,
+        name="gaunt_pairwise_fused",
     )(a1, a2, T1, T2, P)
     return out[:B].reshape(*batch, dout)

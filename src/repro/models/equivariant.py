@@ -303,65 +303,78 @@ class MaceGaunt:
         # no donation: rhat is reused by every layer's conv call
         conv = EquivariantConv(c.L, c.L_edge, c.L, method=c.conv_impl,
                                shard_spec=shard)
-        rhat, dist, mask = _pair_geometry(pos, c.cutoff)
-        geom = None
-        if getattr(c, "fourier_resident", True):
-            if c.conv_impl == "general":
-                geom = conv.filter_rep(rhat[:, :, None, :])
-            elif c.conv_impl == "escn":
-                geom = conv.geometry_rep(rhat[:, :, None, :])
-        x = jnp.zeros((n, c.channels, num_coeffs(c.L)))
-        x = x.at[..., 0].set(params["species"][species])
+        with jax.named_scope("mace.edge"):
+            rhat, dist, mask = _pair_geometry(pos, c.cutoff)
+            geom = None
+            if getattr(c, "fourier_resident", True):
+                if c.conv_impl == "general":
+                    geom = conv.filter_rep(rhat[:, :, None, :])
+                elif c.conv_impl == "escn":
+                    geom = conv.geometry_rep(rhat[:, :, None, :])
+        with jax.named_scope("mace.readout"):
+            x = jnp.zeros((n, c.channels, num_coeffs(c.L)))
+            x = x.at[..., 0].set(params["species"][species])
         # grid-resident gate policy (DESIGN.md §6.5), resolved once for the
         # stack: every layer's selfmix chain shares one workload shape
         grid_gate = _resolve_grid_gate(c, (c.L,) * c.nu, c.L,
                                        batch_hint=n * c.channels,
                                        share_hint=(0,) * c.nu)
         for lp in params["layers"]:
-            rb = radial_basis(dist, c.n_radial, c.cutoff)  # [n,n,R]
-            h = jax.nn.silu(rb @ lp["radial"]["w1"]) @ lp["radial"]["w2"]
-            h = h.reshape(n, n, c.channels, c.L + 1)  # per-edge per-degree weights
-            # messages: conv(x_j, r_ij) summed over j (channel-wise, eSCN path)
-            xj = jnp.broadcast_to(x[None, :, :, :], (n, n, c.channels, x.shape[-1]))
-            m = conv(xj, geom if geom is not None else rhat[:, :, None, :], w1=h)
-            m = jnp.sum(m * mask[:, :, None, None], axis=1)  # [n, C, dim]
-            A = equi_linear(lp["mix"], m, c.L) + x
-            # many-body: nu-fold Gaunt self-product, per-degree weights
-            mb_kw = dict(
-                weights=[jnp.broadcast_to(w, (n, c.channels, c.L + 1))
-                         for w in lp["mb_w"]],
-                shard_spec=shard,  # the chain route honors sharding directly
-                tune=getattr(c, "chain_tune", "heuristic"),
-                dtype=_model_dtype(c),  # storage precision (chain-entry cast)
-            )
-            if grid_gate:
-                # grid-resident gate (DESIGN.md §6.5): the affine gate runs
-                # as a pointwise stage on the selfmix chain's resident
-                # product grid — the whole many-body stage is one region
-                # with one entry + one exit conversion.  The gate cannot
-                # cross the mb_mix channel mix, so this variant gates B
-                # *before* the mix (an equally expressive
-                # reparameterization — fix grid_gate per checkpoint).
-                B = manybody_selfmix(A, c.L, c.nu, Lout=c.L,
-                                     gate_params=lp["gate"], **mb_kw)
-                x = x + equi_linear(lp["mb_mix"], B, c.L)
-            else:
-                B = manybody_selfmix(A, c.L, c.nu, Lout=c.L, **mb_kw)
-                x = x + gate_apply(lp["gate"],
-                                   equi_linear(lp["mb_mix"], B, c.L), c.L)
-        return x[..., 0]  # invariant channels [n, C]
+            with jax.named_scope("mace.radial"):
+                rb = radial_basis(dist, c.n_radial, c.cutoff)  # [n,n,R]
+                h = jax.nn.silu(rb @ lp["radial"]["w1"]) @ lp["radial"]["w2"]
+                h = h.reshape(n, n, c.channels, c.L + 1)  # per-edge per-degree weights
+            with jax.named_scope("mace.conv"):
+                # messages: conv(x_j, r_ij) summed over j (channel-wise, eSCN path)
+                xj = jnp.broadcast_to(x[None, :, :, :], (n, n, c.channels, x.shape[-1]))
+                m = conv(xj, geom if geom is not None else rhat[:, :, None, :], w1=h)
+                m = jnp.sum(m * mask[:, :, None, None], axis=1)  # [n, C, dim]
+            with jax.named_scope("mace.mix_gate"):
+                A = equi_linear(lp["mix"], m, c.L) + x
+            with jax.named_scope("mace.chain"):
+                # many-body: nu-fold Gaunt self-product, per-degree weights
+                mb_kw = dict(
+                    weights=[jnp.broadcast_to(w, (n, c.channels, c.L + 1))
+                             for w in lp["mb_w"]],
+                    shard_spec=shard,  # the chain route honors sharding directly
+                    tune=getattr(c, "chain_tune", "heuristic"),
+                    dtype=_model_dtype(c),  # storage precision (chain-entry cast)
+                )
+                if grid_gate:
+                    # grid-resident gate (DESIGN.md §6.5): the affine gate
+                    # runs as a pointwise stage on the selfmix chain's
+                    # resident product grid — the whole many-body stage is
+                    # one region with one entry + one exit conversion.  The
+                    # gate cannot cross the mb_mix channel mix, so this
+                    # variant gates B *before* the mix (an equally
+                    # expressive reparameterization — fix grid_gate per
+                    # checkpoint).
+                    B = manybody_selfmix(A, c.L, c.nu, Lout=c.L,
+                                         gate_params=lp["gate"], **mb_kw)
+                else:
+                    B = manybody_selfmix(A, c.L, c.nu, Lout=c.L, **mb_kw)
+            with jax.named_scope("mace.mix_gate"):
+                y = equi_linear(lp["mb_mix"], B, c.L)
+                x = x + (y if grid_gate else gate_apply(lp["gate"], y, c.L))
+        with jax.named_scope("mace.readout"):
+            return x[..., 0]  # invariant channels [n, C]
+
+    def _readout(self, params, feat, mask=None):
+        """Per-atom energies through the readout MLP, summed over ``mask``
+        [n] (all atoms when None)."""
+        with jax.named_scope("mace.readout"):
+            e_atom = (jax.nn.silu(feat @ params["readout"]["w1"])
+                      @ params["readout"]["w2"])[:, 0]
+            return jnp.sum(e_atom if mask is None else e_atom * mask)
 
     def energy(self, params, species, pos):
-        feat = self.features(params, species, pos)
-        e_atom = jax.nn.silu(feat @ params["readout"]["w1"]) @ params["readout"]["w2"]
-        return jnp.sum(e_atom)
+        return self._readout(params, self.features(params, species, pos))
 
     def energy_masked(self, params, species, pos, mask):
         """Energy of the atoms selected by ``mask`` [n] (serving: padded
         slots place ghost atoms beyond the cutoff and mask them out here)."""
-        feat = self.features(params, species, pos)
-        e_atom = jax.nn.silu(feat @ params["readout"]["w1"]) @ params["readout"]["w2"]
-        return jnp.sum(e_atom[:, 0] * mask)
+        return self._readout(params, self.features(params, species, pos),
+                             mask)
 
     def energy_forces(self, params, species, pos):
         e, g = jax.value_and_grad(self.energy, argnums=2)(params, species, pos)

@@ -327,6 +327,16 @@ class EquivariantServeEngine:
         cfg = getattr(self.model, "cfg", None)
         from repro.core import engine as _engine
 
+        # each pool's warm-up seconds, as laps of one stopwatch: its seeding
+        # and its compile (the first lap also holds the cache load)
+        lap = self.metrics.clock()
+
+        def charge(pool):
+            nonlocal lap
+            now = self.metrics.clock()
+            self.metrics.observe_warmup(pool.spec.label(), now - lap)
+            lap = now
+
         eng = _engine.get_engine()
         cache = getattr(cfg, "autotune_cache", None) if cfg is not None else None
         if cache is not None:
@@ -363,6 +373,7 @@ class EquivariantServeEngine:
                                            tune="measure", batch_hint=rows,
                                            share_hint=(0,) * cfg.nu, dtype=d,
                                            gate=g)
+                charge(pool)
         for pool in self.pools:
             # transient compile failures (injected or real) retry: a serving
             # host that loses one compile attempt should come up, not die
@@ -374,6 +385,7 @@ class EquivariantServeEngine:
                     self.metrics.counters["warmup_retries"] += 1
                     if attempt == 2:
                         raise
+            charge(pool)
 
     # ------------------------------------------------------------- admission
     def has_active(self) -> bool:

@@ -10,7 +10,11 @@ One `ServeMetrics` instance rides along an engine and its scheduler/pools:
   computed on), both per pool and aggregated;
 - **counters** — submissions, admissions, completions, structured rejections
   (`rejected:<reason>`), steps, early host-side stagings (the async-pipelining
-  overlap hits);
+  overlap hits), and serving steps that compiled after warm-up
+  (`step_compiles`, total and per pool: there should be none);
+- **warm-up** — seconds each pool's warm-up took (`warmup_s`, by pool):
+  its autotune seeding and its step compile, the first pool's share also
+  holding the engine's autotune cache load;
 - **fault tolerance** (DESIGN.md §11) — step failures by kind
   (`step_failures:<kind>`), per-request retries, non-finite slot
   quarantines and bisect passes, replica failovers/restarts and requeued
@@ -25,6 +29,10 @@ One `ServeMetrics` instance rides along an engine and its scheduler/pools:
 
 Everything is plain host-side Python (no device work, no locks — the serving
 loop is single-threaded by design); a fake clock can be injected for tests.
+Where the serving loop's time goes is not kept here: the scheduler and the
+pools write profiler spans (``serve.admit``, ``serve.stage``,
+``serve.dispatch``, ``serve.block``, ``serve.retire``) into the profiler's
+own trace, on the device trace's clock.
 """
 from __future__ import annotations
 
@@ -69,6 +77,7 @@ class ServeMetrics:
         self.atoms_padded = 0      # sum over steps of padded atom-slots
         self.per_pool: dict[str, collections.Counter] = \
             collections.defaultdict(collections.Counter)
+        self.warmup_s: dict[str, float] = {}    # pool -> warm-up seconds
         # fault tolerance (DESIGN.md §11): time-to-recovery samples, the
         # completion sequence (failover ordering proofs read it), and a
         # capped straggler monitor fed by every observed step duration
@@ -88,6 +97,7 @@ class ServeMetrics:
         self.occupancy.clear()
         self.atoms_real = self.atoms_padded = 0
         self.per_pool.clear()
+        self.warmup_s.clear()
         self.recovery_s.clear()
         self.completed_order.clear()
         self.straggler = StragglerMonitor()
@@ -137,6 +147,15 @@ class ServeMetrics:
         if self.straggler.record(self.counters["steps"], dur_s):
             self.counters["straggler_steps"] += 1
             pc["straggler_steps"] += 1
+
+    def observe_step_compile(self, pool: str) -> None:
+        """A pool's serving step compiled at dispatch: a shape that warm-up
+        did not cover, paid for by the requests of that step."""
+        self.counters["step_compiles"] += 1
+        self.per_pool[pool]["step_compiles"] += 1
+
+    def observe_warmup(self, pool: str, dur_s: float) -> None:
+        self.warmup_s[pool] = self.warmup_s.get(pool, 0.0) + dur_s
 
     # ------------------------------------------------------ fault tolerance
     def observe_step_failure(self, pool: str, kind: str) -> None:
@@ -211,6 +230,8 @@ class ServeMetrics:
             "rejected": self.counters["rejected"],
             "steps": self.counters["steps"],
             "staged_early": self.counters["staged_early"],
+            "step_compiles": self.counters["step_compiles"],
+            "warmup_s": sum(self.warmup_s.values()),
             "queue_wait_p50_ms": percentile(self.queue_wait_s, 50) * 1e3,
             "queue_wait_p99_ms": percentile(self.queue_wait_s, 99) * 1e3,
             "latency_p50_ms": percentile(self.total_s, 50) * 1e3,
@@ -233,9 +254,12 @@ class ServeMetrics:
         }
         for name, pc in self.per_pool.items():
             out[f"pool:{name}:steps"] = pc["steps"]
+            out[f"pool:{name}:step_compiles"] = pc["step_compiles"]
             if pc["atoms_padded"]:
                 out[f"pool:{name}:padding_efficiency"] = \
                     pc["atoms_real"] / pc["atoms_padded"]
+        for name, secs in self.warmup_s.items():
+            out[f"pool:{name}:warmup_s"] = secs
         for k, v in self.counters.items():
             if k.startswith(("rejected:", "step_failures:", "retries:",
                              "failovers:")):

@@ -54,6 +54,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import faults
 
@@ -197,8 +198,9 @@ class SlotPool:
         observable, not just asserted."""
         if self._staged is not None and not self._dirty:
             return
-        self._staged = (jnp.asarray(self.species), jnp.asarray(self.pos),
-                        jnp.asarray(self.mask))
+        with TraceAnnotation("serve.stage", pool=self.spec.label()):
+            self._staged = (jnp.asarray(self.species), jnp.asarray(self.pos),
+                            jnp.asarray(self.mask))
         self._dirty = False
         if early and self.metrics is not None:
             self.metrics.observe_staged_early(self.spec.label())
@@ -239,11 +241,17 @@ class SlotPool:
         if self._donate:
             self._staged = None          # donated — never touch again
         t0 = self.clock()
+        compiled = self._step_fn._cache_size()
         try:
-            e, f = self._step_fn(self.params, sp, p, m)
+            with TraceAnnotation("serve.dispatch", pool=self.spec.label()):
+                e, f = self._step_fn(self.params, sp, p, m)
         except Exception:
             self._on_step_failure(active, "step_raised")
             return None
+        if self.metrics is not None and \
+                self._step_fn._cache_size() > compiled:
+            # a step compiled here, after warm-up: serving paid for it
+            self.metrics.observe_step_compile(self.spec.label())
         return _Inflight(active, e, f, t0)
 
     def finish_step(self, h: _Inflight) -> list:
@@ -255,12 +263,21 @@ class SlotPool:
         outputs route into `_on_step_failure` — non-finite outputs
         quarantine ONLY the offending slots (bucket-mates retire normally;
         a collectively failing batch is bisected first)."""
+        label = self.spec.label()
         try:
-            e = np.asarray(h.energy)   # blocks until the device finishes
-            f = np.asarray(h.forces)
+            with TraceAnnotation("serve.block", pool=label):
+                e = np.asarray(h.energy)   # blocks until the device finishes
+                f = np.asarray(h.forces)
         except Exception:
             self._on_step_failure(h.active, "step_raised")
             return []
+        with TraceAnnotation("serve.retire", pool=label):
+            return self._retire(h, e, f)
+
+    def _retire(self, h: _Inflight, e, f) -> list:
+        """The host half of `finish_step` once the results are read:
+        watchdog and finiteness checks, retirement, relaxation writes and
+        completion stamps."""
         dur = self.clock() - h.t0
         timed_out = (self.step_timeout_s is not None
                      and dur > self.step_timeout_s)

@@ -34,6 +34,8 @@ import heapq
 import time
 from typing import Callable, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["AdmissionQueue", "Scheduler",
            "REASON_DEADLINE", "REASON_INVALID", "REASON_TOO_LARGE"]
 
@@ -137,34 +139,35 @@ class Scheduler:
         Touches only host state (queue bookkeeping + slot-array writes), so
         the engine step may safely run it as the ``overlap`` callback while
         a device step is in flight.  Returns the number admitted."""
-        now = self.clock()
-        for req in self.queue.expire(now):
-            self._reject(req, REASON_DEADLINE,
-                         f"queued {now - req._submit_t:.3f}s > "
-                         f"deadline {req.deadline}s")
-        admitted = 0
-        blocked: list = []
-        while True:
-            req = self.queue.pop()
-            if req is None:
-                break
-            if _deadline_expired(req, now):
-                self._reject(req, REASON_DEADLINE)
-                continue
-            err = self.engine.validate(req)
-            if err is not None:
-                reason, detail = err if isinstance(err, tuple) else (err, "")
-                self._reject(req, reason, detail)
-                continue
-            if self.engine.try_admit(req):
-                admitted += 1
-                if self.metrics is not None:
-                    self.metrics.observe_admit(req, self.clock())
-            else:
-                blocked.append(req)
-        for req in blocked:
-            self.queue.requeue(req)
-        return admitted
+        with TraceAnnotation("serve.admit"):
+            now = self.clock()
+            for req in self.queue.expire(now):
+                self._reject(req, REASON_DEADLINE,
+                             f"queued {now - req._submit_t:.3f}s > "
+                             f"deadline {req.deadline}s")
+            admitted = 0
+            blocked: list = []
+            while True:
+                req = self.queue.pop()
+                if req is None:
+                    break
+                if _deadline_expired(req, now):
+                    self._reject(req, REASON_DEADLINE)
+                    continue
+                err = self.engine.validate(req)
+                if err is not None:
+                    reason, detail = err if isinstance(err, tuple) else (err, "")
+                    self._reject(req, reason, detail)
+                    continue
+                if self.engine.try_admit(req):
+                    admitted += 1
+                    if self.metrics is not None:
+                        self.metrics.observe_admit(req, self.clock())
+                else:
+                    blocked.append(req)
+            for req in blocked:
+                self.queue.requeue(req)
+            return admitted
 
     # ------------------------------------------------------------ stepping
     def pump(self, poll: Optional[Callable[[], None]] = None) -> bool:

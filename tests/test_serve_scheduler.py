@@ -274,3 +274,66 @@ def test_small_requests_never_compile_the_large_bucket(small_model):
     big = EquivariantRequest(*_mol(10, seed=99), rid=99)
     eng.run([big])
     assert big.done and large_pool.compiled() and large_pool.steps_run == 1
+
+
+# ------------------------------------------------ spans and warm-up counters
+
+SERVE_SPANS = {"serve.admit", "serve.stage", "serve.dispatch", "serve.block",
+               "serve.retire"}
+
+
+def test_serving_loop_writes_its_spans_into_the_profilers_trace(
+        small_model, tmp_path):
+    """Under `jax.profiler` the scheduler and the pools write a host span
+    for each part of a round, the pool's spans tagged with its bucket."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    model, params = small_model
+    eng = EquivariantServeEngine(model, params, buckets=[(4, 2)])
+    eng.warmup()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run([EquivariantRequest(*_mol(3, seed=i), rid=i)
+                 for i in range(3)])
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                            "*.xplane.pb"))
+    events = [e for p in ProfileData.from_file(path).planes
+              if p.name.startswith("/host:") for line in p.lines
+              for e in line.events if e.name.startswith("serve.")]
+    assert {e.name for e in events} == SERVE_SPANS
+    pooled = [dict(e.stats) for e in events if e.name != "serve.admit"]
+    assert pooled and all(s == {"pool": "b4"} for s in pooled)
+
+
+def test_warmed_pools_compile_nothing_while_serving(small_model):
+    model, params = small_model
+    eng = EquivariantServeEngine(model, params, buckets=[(4, 2), (8, 1)])
+    eng.warmup()
+    m = eng.metrics
+    assert set(m.warmup_s) == {"b4", "b8"}
+    assert all(s > 0 for s in m.warmup_s.values())
+    eng.run([EquivariantRequest(*_mol(n, seed=n), rid=n)
+             for n in (2, 3, 4, 6, 7)])
+    assert m.counters["steps"] > 0 and m.counters["step_compiles"] == 0
+    s = m.summary()
+    assert s["step_compiles"] == 0
+    assert s["warmup_s"] == pytest.approx(sum(m.warmup_s.values()))
+    assert s["pool:b8:warmup_s"] == m.warmup_s["b8"]
+
+
+def test_pool_left_out_of_warmup_counts_its_compile_once(small_model):
+    model, params = small_model
+    eng = EquivariantServeEngine(model, params, buckets=[(4, 2), (8, 1)])
+    eng.pools.pools[0].warmup_compile()     # warm the 4-atom pool alone
+    eng.run([EquivariantRequest(*_mol(3, seed=1), rid=1)])
+    assert eng.metrics.counters["step_compiles"] == 0
+    for rid in (2, 3):
+        eng.run([EquivariantRequest(*_mol(6, seed=rid), rid=rid)])
+    s = eng.metrics.summary()
+    assert s["step_compiles"] == 1
+    assert s["pool:b8:step_compiles"] == 1 and s["pool:b4:step_compiles"] == 0
+    assert eng.metrics.warmup_s == {}

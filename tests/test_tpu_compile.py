@@ -9,6 +9,7 @@ load the TPU library.  Shapes are the serving preset's selfmix key: a
 64-atom slot of 64 channels gives 4096 rows of degree-2 irreps.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +57,12 @@ def _hlo(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _named_kernel(hlo: str, name: str) -> bool:
+    """The kernel's custom call carries its stable name, which a trace
+    shows as the op's name."""
+    return re.search(rf"%{name}[.\d]* = \S+ custom-call\(", hlo) is not None
+
+
 @pytest.mark.parametrize("gated", [False, True])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_chain_kernel_compiles_for_v5e(one_chip, gated, dtype):
@@ -69,7 +76,9 @@ def test_chain_kernel_compiles_for_v5e(one_chip, gated, dtype):
             [x] * nu, (L,) * nu, L, interpret=False, dtype=dtype,
             gate=(gs, gb) if gated else None)
 
-    assert "tpu_custom_call" in _hlo(run, x, g, g)
+    hlo = _hlo(run, x, g, g)
+    assert "tpu_custom_call" in hlo
+    assert _named_kernel(hlo, "gaunt_chain_fused")
 
 
 def test_pairwise_kernel_compiles_for_v5e(one_chip):
@@ -82,4 +91,6 @@ def test_pairwise_kernel_compiles_for_v5e(one_chip):
     def run(a, b):
         return gaunt_fused_pallas(a, b, L1, L2, Lout, interpret=False)
 
-    assert "tpu_custom_call" in _hlo(run, x1, x2)
+    hlo = _hlo(run, x1, x2)
+    assert "tpu_custom_call" in hlo
+    assert _named_kernel(hlo, "gaunt_pairwise_fused")
