@@ -297,8 +297,8 @@ def run_chain(csv=True):
     # The rotation-aligned conv used to rebuild align_rotation + the CG
     # Wigner recursion from the SAME layer-constant rhat inside every
     # layer's dispatch; `EquivariantConv.geometry_rep` hoists them once per
-    # geometry (ROADMAP "eSCN geometry residency") and the aligned banded
-    # conv consumes the precomputed WignerBlocks through its bucket.
+    # geometry (ROADMAP "eSCN geometry residency") and the aligned conv
+    # consumes the precomputed WignerBlocks through its bucket.
     from repro.core.conv import EquivariantConv
 
     for name, L, n_layers, B in [("escn_wigner_L2_x8_B512", 2, 8, 512),

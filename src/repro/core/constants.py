@@ -2,8 +2,8 @@
 
 Every precomputed tensor used by any Gaunt backend lives behind exactly one
 lru-cached builder in this module: SH<->Fourier conversion tensors (dense and
-packed), packed-layout gather maps, the eSCN filter column and banded-conv
-index, the Wigner-recursion CG blocks, and the fused collocation matrices
+packed), packed-layout gather maps, the eSCN m=0 Gaunt coupling, the
+Wigner-recursion CG blocks, and the fused collocation matrices
 T1/T2/P.  This replaces the per-module ``lru_cache`` constellations that used
 to live in ``core/gaunt.py``, ``core/conv.py`` and ``kernels/gaunt_fused.py``.
 
@@ -33,8 +33,7 @@ __all__ = [
     "y_half",
     "z_half",
     "pack_index",
-    "filter_fourier_col",
-    "conv_u_index",
+    "escn_coupling",
     "cg_11_blocks",
     "fused_matrices",
     "chain_matrices",
@@ -127,36 +126,27 @@ def pack_index(L: int) -> tuple[np.ndarray, np.ndarray]:
 
 
 @lru_cache(maxsize=None)
-def filter_fourier_col(L2: int, cdtype: str = "complex64") -> np.ndarray:
-    """u-column (v=0) Fourier coefficients of S_{l,0}, stacked [L2+1, 2L2+1]."""
-    y = _y_raw(L2)
-    cols = np.stack([y[idx(l, 0), :, L2] for l in range(L2 + 1)], axis=0)
-    return cols.astype(cdtype)
+def escn_coupling(L1: int, L2: int, Lout: int, dtype: str = "float32") -> np.ndarray:
+    """C [L2+1, (L1+1)^2, (Lout+1)^2]: the Gaunt product with the aligned filter.
 
-
-@lru_cache(maxsize=None)
-def conv_u_index(L1: int, L2: int) -> tuple[np.ndarray, np.ndarray]:
-    """Index/mask for the banded 1D convolution along u.
-
-    out[u3] = sum_{u1} F1[u1] * k[u3 - u1] with centered indices;
-    idx[i3, i1] = i3 - i1 into the kernel array of length 2L2+1.
+    In the frame whose zenith is the edge, Y(e_z) has only m=0 coefficients,
+    S_{l,0}(e_z) = sqrt((2l+1)/4pi); slice l is the real Gaunt tensor at the
+    filter column idx(l, 0) times that value.  So for per-degree filter
+    weights w, x (x)_Gaunt (w . Y(e_z)) = einsum('i,l,lik->k', x, w, C), and
+    with no weights it is x @ C.sum(0).  Gaunt selection keeps it
+    m-conserving: C[l, i, k] != 0 only where i and k have the same m.
     """
-    n1, n2 = 2 * L1 + 1, 2 * L2 + 1
-    N = n1 + n2 - 1
-    i3 = np.arange(N)[:, None]
-    i1 = np.arange(n1)[None, :]
-    k = i3 - i1  # in [ -(n1-1), N-1 ]
-    valid = (k >= 0) & (k < n2)
-    return np.where(valid, k, 0).astype(np.int32), valid.astype(np.float32)
+    G = gaunt_dense(L1, L2, Lout, "float64")
+    C = np.stack([G[:, idx(l, 0), :] * math.sqrt((2 * l + 1) / (4 * math.pi))
+                  for l in range(L2 + 1)], axis=0)
+    return C.astype(dtype)
 
 
 @lru_cache(maxsize=None)
 def cg_11_blocks(L: int) -> tuple[np.ndarray, ...]:
-    """CG blocks C_{(l-1,1)->l} for the Wigner-from-rotmat recursion."""
-    return tuple(
-        real_clebsch_gordan_block(l - 1, 1, l).astype(np.float32)
-        for l in range(2, L + 1)
-    )
+    """CG blocks C_{(l-1,1)->l} for the Wigner-from-rotmat recursion
+    (float64; the recursion casts them to the rotation's dtype)."""
+    return tuple(real_clebsch_gordan_block(l - 1, 1, l) for l in range(2, L + 1))
 
 
 # --------------------------------------------------------------------------
@@ -366,7 +356,7 @@ def gaunt_dense(L1: int, L2: int, Lout: int, dtype: str = "float32") -> np.ndarr
 
 _CACHED = (
     _y_raw, _z_raw, y_dense, z_dense, y_packed, z_packed, y_half, z_half,
-    pack_index, filter_fourier_col, conv_u_index, cg_11_blocks, fused_matrices,
+    pack_index, escn_coupling, cg_11_blocks, fused_matrices,
     chain_matrices, chain_sample_sh, chain_sample_grid, chain_project_sh,
     chain_project_grid, chain_l0, quad_sample_sh, quad_project_sh,
     quad_sample_fourier, quad_project_fourier, gaunt_dense,

@@ -7,10 +7,10 @@ general : evaluate the SH filter Y(r_hat) directly and run the Gaunt TP.
 escn    : Passaro & Zitnick insight adapted to our z-up convention —
           rotate the frame so the edge lands on the zenith; the filter then
           has only m = 0 components,  S_{l,m}(e_z) = delta_{m0} sqrt((2l+1)/4pi),
-          so its torus-Fourier coefficients occupy the single v = 0 column
-          (O(L^2) conversion, Eqn. 58 of the paper) and the 2D convolution
-          degenerates to a per-v 1D convolution along u (a small banded
-          matmul — MXU-friendly).  out = D^T [ (D x) (x)_Gaunt Y(e_z) ].
+          so the Gaunt product with it is a fixed real linear map of the
+          rotated coefficients, m-conserving and precontracted once per
+          degree triple (`constants.escn_coupling`): one small real matmul
+          per row.  out = D^T [ (D x) (x)_Gaunt Y(e_z) ].
 
 Wigner rotations are built *differentiably* from the rotation matrix by the
 CG intertwiner recursion  D^l = C^T (D^{l-1} (x) D^1) C  — no Euler angles on
@@ -115,7 +115,7 @@ class WignerBlocks:
 
     @classmethod
     def from_rhat(cls, rhat, L: int) -> "WignerBlocks":
-        R = align_rotation(rhat.astype(jnp.float32))
+        R = align_rotation(rhat.astype(jnp.promote_types(rhat.dtype, jnp.float32)))
         return cls(tuple(wigner_blocks_from_rotmat(L, R)))
 
 

@@ -1438,10 +1438,8 @@ def _cost_escn(key: PlanKey) -> float:
     Lw = max(key.L1, key.Lout)
     wigner = B * sum((2 * l + 1) ** 4 for l in range(2, Lw + 1)) + \
         2.0 * B * sum((2 * l + 1) ** 2 for l in range(Lw + 1))
-    s2f = 2.0 * B * d1 * n1 * n1
-    banded = _C_CPLX * B * N * n1 * n1
-    proj = _C_CPLX * B * N * N * do
-    return wigner + s2f + banded + proj + _OVERHEAD * 10
+    coupling = 2.0 * B * d1 * (key.L2 + 1) * do
+    return wigner + coupling + _OVERHEAD * 10
 
 
 # --------------------------------------------------------------------------
@@ -1630,23 +1628,23 @@ def _build_fused(key: PlanKey, pallas: bool) -> Callable:
 
 
 def _build_escn(key: PlanKey) -> Callable:
-    cd = _CDTYPE[key.dtype]
+    # in the edge-aligned frame the filter is Y(e_z), m=0 only, so the Gaunt
+    # product with it is one real linear map on the rotated rows (DESIGN.md
+    # §3.5): C [L2+1, d1, do] per filter degree, M = C summed over degrees
+    # when no filter weights are given.  Both are a few hundred numbers at
+    # most, so they stay at the accumulation dtype (as the fused backends' P)
+    acc = jnp.dtype(key.acc_dtype)
     rd = _RDTYPE[key.dtype]
     L1, L2, Lout = key.L1, key.L2, key.Lout
-    constants.y_dense(L1, cd)
-    constants.z_dense(L1 + L2, Lout, cd)
-    constants.filter_fourier_col(L2, cd)
-    constants.conv_u_index(L1, L2)
+    C = constants.escn_coupling(L1, L2, Lout, key.acc_dtype)
+    M = C.sum(axis=0)
     constants.cg_11_blocks(max(L1, Lout))
-    fl0 = np.array([math.sqrt((2 * l + 1) / (4 * math.pi)) for l in range(L2 + 1)],
-                   dtype=np.float32)
     geometry = key.opt("geometry")
 
     def apply_conv(x, rhat, w1=None, w2=None, w3=None):
         # lazy: conv.py routes through the engine, so import its helpers at call
         from .conv import (WignerBlocks, align_rotation, apply_wigner_blocks,
                            wigner_blocks_from_rotmat)
-        from .gaunt import fourier_to_sh, sh_to_fourier
 
         x = _wmul(x, w1, L1)
         if geometry == "wigner":
@@ -1662,25 +1660,16 @@ def _build_escn(key: PlanKey) -> Callable:
                                  f"need max(L1, Lout) = {max(L1, Lout)}")
             Ds = list(rhat.blocks)
         else:
-            R = align_rotation(rhat.astype(jnp.float32))
+            R = align_rotation(rhat.astype(jnp.promote_types(rhat.dtype, jnp.float32)))
             Ds = wigner_blocks_from_rotmat(max(L1, Lout), R)
         x_rot = apply_wigner_blocks(Ds[: L1 + 1], x)
-        F1 = sh_to_fourier(x_rot, L1, "dense", jnp.dtype(cd))  # [..., n1, n1]
-        # filter coefficients: only m=0 -> single v=0 column, O(L^2)
-        fl = jnp.asarray(fl0, dtype=rd)
-        if w2 is not None:
-            fl = fl * w2.astype(rd)
-        cols = jnp.asarray(constants.filter_fourier_col(L2, cd))
-        k = jnp.einsum("...l,lu->...u", fl.astype(cols.dtype), cols)  # [..., 2L2+1]
-        # banded 1D conv along u for every v column (v support unchanged)
-        gidx, mask = constants.conv_u_index(L1, L2)
-        kmat = k[..., jnp.asarray(gidx)] * jnp.asarray(mask, dtype=rd)  # [..., N, n1]
-        F3 = jnp.einsum("...ti,...iv->...tv", kmat, F1)  # [..., N, n1(v)]
-        # pad v axis to the full output grid (v support still |v| <= L1)
-        pv = (2 * (L1 + L2) + 1 - (2 * L1 + 1)) // 2
-        F3 = jnp.pad(F3, [(0, 0)] * (F3.ndim - 1) + [(pv, pv)])
-        out_rot = fourier_to_sh(F3, L1 + L2, Lout, "dense", rd)
-        out = apply_wigner_blocks(Ds[: Lout + 1], out_rot, transpose=True)
+        if w2 is None:
+            out_rot = jnp.einsum("...i,ik->...k", x_rot, jnp.asarray(M),
+                                 preferred_element_type=acc)
+        else:
+            out_rot = jnp.einsum("...i,...l,lik->...k", x_rot, w2.astype(acc),
+                                 jnp.asarray(C), preferred_element_type=acc)
+        out = apply_wigner_blocks(Ds[: Lout + 1], out_rot.astype(rd), transpose=True)
         return _wmul(out, w3, Lout)
 
     return apply_conv

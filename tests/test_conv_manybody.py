@@ -1,9 +1,10 @@
 """Equivariant convolution (general + eSCN-sparsity) and many-body products."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import so3
+from repro.core import constants, so3
 from repro.core.cg import gaunt_einsum_reference
 from repro.core.conv import (
     EquivariantConv,
@@ -11,10 +12,11 @@ from repro.core.conv import (
     apply_wigner_blocks,
     wigner_blocks_from_rotmat,
 )
-from repro.core.irreps import num_coeffs
+from repro.core.irreps import m_array, num_coeffs
 from repro.core.manybody import manybody_gaunt_product, manybody_selfmix
+from repro.core.rep import conversion_stats
 from repro.core.so3 import real_sph_harm, real_sph_harm_jax
-from repro.testing import random_array, random_unit_vectors
+from repro.testing import assert_close, random_array, random_unit_vectors
 
 
 def _rand(shape, seed=0):
@@ -54,7 +56,8 @@ def test_apply_wigner_matches_sh_rotation():
     np.testing.assert_allclose(np.asarray(S_rot), np.asarray(ref), atol=1e-4)
 
 
-@pytest.mark.parametrize("L1,L2,Lout", [(2, 2, 4), (3, 2, 3), (2, 3, 5), (1, 4, 5)])
+@pytest.mark.parametrize("L1,L2,Lout", [(2, 2, 4), (3, 2, 3), (2, 3, 5), (1, 4, 5),
+                                        (1, 3, 1), (2, 3, 2)])
 def test_escn_conv_matches_general_and_oracle(L1, L2, Lout):
     x = _rand((16, num_coeffs(L1)), 4)
     r = _rand_dirs(16, 5)
@@ -80,6 +83,59 @@ def test_escn_conv_weights():
         np.asarray(general(x, r, w1, w2, w3)),
         atol=3e-4,
     )
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+def test_escn_conv_grad_matches_general(dtype):
+    """The force path differentiates the conv through x and the edge
+    direction: eSCN and general give the same gradients.  rhat is the
+    normalised edge vector, as in the models, so both see only its
+    tangential change."""
+    L1, L2, Lout = 2, 3, 2
+    with jax.enable_x64(dtype == "float64"):
+        rd = jnp.dtype(dtype)
+        cd = jnp.complex128 if dtype == "float64" else jnp.complex64
+        x = jnp.asarray(random_array((12, num_coeffs(L1)), 20), rd)
+        v = jnp.asarray(random_array((12, 3), 21), rd)
+        g = jnp.asarray(random_array((12, num_coeffs(Lout)), 22), rd)
+        w1 = jnp.asarray(random_array((12, L1 + 1), 23), rd)
+
+        def grads(method):
+            conv = EquivariantConv(L1, L2, Lout, method=method, cdtype=cd, rdtype=rd)
+
+            def loss(x, v):
+                rhat = v / jnp.linalg.norm(v, axis=-1, keepdims=True)
+                return jnp.sum(conv(x, rhat, w1=w1) * g)
+
+            return jax.grad(loss, argnums=(0, 1))(x, v)
+
+        (gx_e, gv_e), (gx_g, gv_g) = grads("escn"), grads("general")
+        assert gx_e.dtype == gv_e.dtype == rd
+        assert_close(gx_e, gx_g, dtype=dtype, tier="loose")
+        assert_close(gv_e, gv_g, dtype=dtype, tier="loose")
+
+
+@pytest.mark.parametrize("L", [1, 2])
+def test_escn_coupling_conserves_m_and_skips_fourier(L):
+    """The aligned coupling only joins input and output coefficients of the
+    same m, and an escn_aligned call runs no SH<->Fourier conversion; the
+    general conv on the same operands does, so the counter is live."""
+    C = constants.escn_coupling(L, 3, L)
+    assert C.shape == (4, num_coeffs(L), num_coeffs(L))
+    m = m_array(L)
+    nz = np.abs(C) > 1e-12
+    assert nz.any()
+    assert not (nz & (m[:, None] != m[None, :])).any()
+    # fresh operand shapes: a warm bucket jit would count nothing either way
+    x = _rand((23, 3, num_coeffs(L)), 30 + L)
+    r = _rand_dirs(23, 32 + L)[:, None, :]
+    with conversion_stats(fresh=True) as c:
+        out = EquivariantConv(L, 3, L, method="escn")(x, r)
+    assert (c["sh_to_fourier"], c["fourier_to_sh"]) == (0, 0)
+    with conversion_stats(fresh=True) as c:
+        ref = EquivariantConv(L, 3, L, method="general")(x, r)
+    assert c["sh_to_fourier"] > 0 and c["fourier_to_sh"] > 0
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-4)
 
 
 def test_conv_equivariance():

@@ -1,4 +1,5 @@
-"""The Gaunt collocation kernels compile for a TPU v5e.
+"""The Gaunt collocation kernels and the served eSCN conv compile for a
+TPU v5e.
 
 Each test lowers a kernel with interpret mode off and compiles it for one
 chip of a *described* ``v5e:2x2`` topology: the TPU compiler installed with
@@ -94,3 +95,26 @@ def test_pairwise_kernel_compiles_for_v5e(one_chip):
     hlo = _hlo(run, x1, x2)
     assert "tpu_custom_call" in hlo
     assert _named_kernel(hlo, "gaunt_pairwise_fused")
+
+
+def test_escn_conv_compiles_without_complex_for_v5e(one_chip):
+    """The force path of the served conv (the MD cell's key: L=1 features,
+    L_edge=3 filter, [atoms, atoms, channels] rows) compiles for v5e as real
+    arithmetic: the aligned filter's Gaunt coupling is one real matmul, so
+    no complex64 value may appear in the forward or the backward."""
+    from repro.core.conv import EquivariantConv
+
+    conv = EquivariantConv(1, 3, 1, method="escn")
+    n, C = 128, 128
+    x = jax.ShapeDtypeStruct((n, n, C, num_coeffs(1)), jnp.float32,
+                             sharding=one_chip)
+    r = jax.ShapeDtypeStruct((n, n, 1, 3), jnp.float32, sharding=one_chip)
+
+    def energy(x, r):
+        return jnp.sum(conv(x, r) ** 2)
+
+    lowered = jax.jit(jax.value_and_grad(energy, argnums=(0, 1))).lower(x, r)
+    assert "f32[" in lowered.compile().as_text()
+    # the TPU compiler splits complex ops into real ones, so the guard reads
+    # the program as traced, before the compiler's passes
+    assert "c64[" not in lowered.as_text(dialect="hlo")
