@@ -11,7 +11,8 @@ from repro.configs.zamba2_2p7b import zamba2_2p7b
 from repro.configs.rwkv6_3b import rwkv6_3b
 from repro.configs.whisper_base import whisper_base
 from repro.configs.qwen2_vl_72b import qwen2_vl_72b
-from repro.configs.gaunt_ff import gaunt_mace_ff, gaunt_segnn_nbody, gaunt_equiformer_selfmix
+from repro.configs.gaunt_ff import (equiformer_v2_tiny, gaunt_mace_ff,
+                                    gaunt_segnn_nbody)
 
 ALL_LM_ARCHS = [
     "dbrx-132b", "qwen2-moe-a2.7b", "qwen1.5-32b", "qwen2-0.5b",

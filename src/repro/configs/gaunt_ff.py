@@ -7,7 +7,7 @@ import dataclasses
 @dataclasses.dataclass(frozen=True)
 class EquivariantConfig:
     name: str
-    kind: str  # mace | segnn | equiformer_selfmix
+    kind: str  # mace | segnn
     L: int = 2           # max feature degree
     L_edge: int = 2      # SH filter degree
     channels: int = 64
@@ -73,13 +73,53 @@ class EquivariantConfig:
     serve_buckets: tuple[tuple[int, int], ...] | None = None
 
 
+@dataclasses.dataclass(frozen=True)
+class EquiformerV2Config:
+    """EquiformerV2 (Liao, Shuaibi, Zitnick, Smidt, ICLR 2024) with the Gaunt
+    Selfmix layer of Luo et al. (ICLR 2024, section 5) in every block.  The
+    defaults are the OC20 S2EF-2M model ``equiformer_v2_N@12_L@6_M@2`` of the
+    public Open Catalyst configs; `repro.models.equiformer_v2` holds the
+    equations.  The model runs on a k-nearest-neighbour graph: a serving
+    pool builds it on the host from ``max_neighbors`` and ``max_radius``."""
+    name: str = "eqv2-l6m2-selfmix"
+    n_blocks: int = 12
+    lmax: int = 6
+    mmax: int = 2
+    sphere_channels: int = 128
+    attn_hidden_channels: int = 64
+    num_heads: int = 8
+    attn_alpha_channels: int = 64
+    attn_value_channels: int = 16
+    ffn_hidden_channels: int = 128
+    edge_channels: int = 128
+    max_neighbors: int = 20
+    max_radius: float = 12.0
+    num_distance_basis: int = 600   # Gaussians on [0, max_radius]
+    distance_width: float = 2.0     # Gaussian width, in basis spacings
+    max_num_elements: int = 90
+    # the S^2 grid of the nonlinearities: Gauss-Legendre in cos(theta) times
+    # a uniform phi grid (`core.fourier.s2quad_angles`)
+    grid_theta: int = 18
+    grid_phi: int = 18
+    avg_degree: float = 23.395238876342773   # edge-degree embedding scale
+    avg_num_nodes: float = 77.81317          # energy scale
+
+    @property
+    def n_species(self) -> int:
+        return self.max_num_elements
+
+
 gaunt_mace_ff = EquivariantConfig(
     name="gaunt-mace-ff", kind="mace", L=2, L_edge=3, channels=64, n_layers=2, nu=3
 )
 gaunt_segnn_nbody = EquivariantConfig(
     name="gaunt-segnn-nbody", kind="segnn", L=1, L_edge=1, channels=32, n_layers=4
 )
-gaunt_equiformer_selfmix = EquivariantConfig(
-    name="gaunt-equiformer-selfmix", kind="equiformer_selfmix", L=4, L_edge=4,
-    channels=32, n_layers=2
-)
+# every width cut down, for the CPU tests (EquiformerV2Config() itself holds
+# the published OC20 settings)
+equiformer_v2_tiny = EquiformerV2Config(
+    name="eqv2-tiny", n_blocks=2, lmax=2, mmax=1, sphere_channels=8,
+    attn_hidden_channels=4, num_heads=2, attn_alpha_channels=4,
+    attn_value_channels=2, ffn_hidden_channels=8, edge_channels=8,
+    max_neighbors=4, max_radius=6.0, num_distance_basis=16,
+    max_num_elements=10, grid_theta=6, grid_phi=6)

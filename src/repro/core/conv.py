@@ -12,6 +12,11 @@ escn    : Passaro & Zitnick insight adapted to our z-up convention —
           degree triple (`constants.escn_coupling`): one small real matmul
           per row.  out = D^T [ (D x) (x)_Gaunt Y(e_z) ].
 
+`so2_conv` is the learned counterpart in the same edge frame (eSCN's SO(2)
+convolution, as EquiformerV2 uses it): of the rotated coefficients it keeps
+the orders |m| <= M (`reduced_rows`) and applies one linear map per m that
+pairs +m with -m.
+
 Wigner rotations are built *differentiably* from the rotation matrix by the
 CG intertwiner recursion  D^l = C^T (D^{l-1} (x) D^1) C  — no Euler angles on
 the hot path (TPU adaptation; eSCN's CUDA code uses host-precomputed Wigner
@@ -32,6 +37,8 @@ __all__ = [
     "wigner_blocks_from_rotmat",
     "apply_wigner_blocks",
     "WignerBlocks",
+    "reduced_rows",
+    "so2_conv",
     "EquivariantConv",
 ]
 
@@ -117,6 +124,46 @@ class WignerBlocks:
     def from_rhat(cls, rhat, L: int) -> "WignerBlocks":
         R = align_rotation(rhat.astype(jnp.promote_types(rhat.dtype, jnp.float32)))
         return cls(tuple(wigner_blocks_from_rotmat(L, R)))
+
+
+def reduced_rows(L: int, M: int) -> np.ndarray:
+    """Packed indices l*l + l + m of the edge frame's rows with |m| <= M, in
+    the order the SO(2) convolutions read them: m=0 for l=0..L, then for
+    each m=1..M, +m for l=m..L and -m for l=m..L."""
+    rows = [l * l + l for l in range(L + 1)]
+    for m in range(1, M + 1):
+        rows += [l * l + l + m for l in range(m, L + 1)]
+        rows += [l * l + l - m for l in range(m, L + 1)]
+    return np.asarray(rows, np.int32)
+
+
+def so2_conv(p, u, L: int, M: int, n_out: int, extra: int = 0, rad=None):
+    """The SO(2) convolution of edge-frame features u [..., R, Ci] (rows as
+    `reduced_rows`).  ``rad`` [..., sum_m (L-m+1) Ci] scales the inputs of
+    each order m (shared by +m and -m).  -> (y [..., R, n_out],
+    extra invariant outputs [..., extra])."""
+    ci = u.shape[-1]
+    lead = u.shape[:-2]
+    n0 = L + 1
+    x0 = u[..., :n0, :].reshape(*lead, n0 * ci)
+    if rad is not None:
+        x0 = x0 * rad[..., :n0 * ci]
+    y0 = x0 @ p["w0"] + p["b0"]
+    ex, pieces = y0[..., :extra], [y0[..., extra:].reshape(*lead, n0, n_out)]
+    row, off = n0, n0 * ci
+    for m in range(1, M + 1):
+        nm = L - m + 1
+        xp = u[..., row:row + nm, :].reshape(*lead, nm * ci)
+        xm = u[..., row + nm:row + 2 * nm, :].reshape(*lead, nm * ci)
+        if rad is not None:
+            r = rad[..., off:off + nm * ci]
+            xp, xm = xp * r, xm * r
+        yp, ym = xp @ p[f"w{m}"], xm @ p[f"w{m}"]
+        half = nm * n_out
+        pieces.append((yp[..., :half] - ym[..., half:]).reshape(*lead, nm, n_out))
+        pieces.append((ym[..., :half] + yp[..., half:]).reshape(*lead, nm, n_out))
+        row, off = row + 2 * nm, off + nm * ci
+    return jnp.concatenate(pieces, axis=-2), ex
 
 
 class EquivariantConv:

@@ -535,6 +535,10 @@ class SelfmixLayer:
         }
 
     def __call__(self, params, x):
+        return x + self.interaction(params, x)
+
+    def interaction(self, params, x):
+        """The layer without its residual: mix(GauntTP(w1 . x, w2 . x))."""
         L = self.L
         if self.tp_impl == "gaunt" and self.resident:
             from repro.core import engine as _engine
@@ -565,4 +569,4 @@ class SelfmixLayer:
             yw = x * expand_degree_weights(params["w2"], L)
             y = cg_full_tensor_product(xw, yw, L, L, L) * expand_degree_weights(
                 params["w3"][: L + 1], L)
-        return x + equi_linear(params["mix"], y, L)
+        return equi_linear(params["mix"], y, L)

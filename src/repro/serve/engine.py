@@ -249,10 +249,11 @@ class EquivariantRequest:
 
 
 class EquivariantServeEngine:
-    """Continuous batching for a MaceGaunt-style model over size-bucketed
-    atom-padded slot pools: every step dispatches one fused batched
-    evaluation per active bucket, pipelining the next step's admissions
-    against the in-flight device compute."""
+    """Continuous batching for a MaceGaunt-style model, or one served on a
+    host-built neighbour graph (EquiformerV2, `serve/pools.py`), over
+    size-bucketed atom-padded slot pools: every step dispatches one fused
+    batched evaluation per active bucket, pipelining the next step's
+    admissions against the in-flight device compute."""
 
     def __init__(self, model, params, n_slots: int = 4, max_atoms: int = 16,
                  warmup: bool = False, buckets=None, clock=time.monotonic,

@@ -12,6 +12,10 @@ One `ServeMetrics` instance rides along an engine and its scheduler/pools:
   (`rejected:<reason>`), steps, early host-side stagings (the async-pipelining
   overlap hits), and serving steps that compiled after warm-up
   (`step_compiles`, total and per pool: there should be none);
+- **neighbour graphs** — for a model served on one, host seconds spent
+  building slots' graphs (`graph_s`), the real edges built
+  (`graph_edges`) and the edge slots they were built into
+  (`graph_edge_slots`: rebuilt slots x max_atoms x k), all counters;
 - **warm-up** — seconds each pool's warm-up took (`warmup_s`, by pool):
   its autotune seeding and its step compile, the first pool's share also
   holding the engine's autotune cache load;
@@ -30,9 +34,9 @@ One `ServeMetrics` instance rides along an engine and its scheduler/pools:
 Everything is plain host-side Python (no device work, no locks — the serving
 loop is single-threaded by design); a fake clock can be injected for tests.
 Where the serving loop's time goes is not kept here: the scheduler and the
-pools write profiler spans (``serve.admit``, ``serve.stage``,
-``serve.dispatch``, ``serve.block``, ``serve.retire``) into the profiler's
-own trace, on the device trace's clock.
+pools write profiler spans (``serve.admit``, ``serve.graph``,
+``serve.stage``, ``serve.dispatch``, ``serve.block``, ``serve.retire``)
+into the profiler's own trace, on the device trace's clock.
 """
 from __future__ import annotations
 
@@ -154,6 +158,13 @@ class ServeMetrics:
         self.counters["step_compiles"] += 1
         self.per_pool[pool]["step_compiles"] += 1
 
+    def observe_graph(self, dur_s: float, edges: int,
+                      edge_slots: int) -> None:
+        """A pool rebuilt the neighbour graphs of its stale slots."""
+        self.counters["graph_s"] += dur_s
+        self.counters["graph_edges"] += edges
+        self.counters["graph_edge_slots"] += edge_slots
+
     def observe_warmup(self, pool: str, dur_s: float) -> None:
         self.warmup_s[pool] = self.warmup_s.get(pool, 0.0) + dur_s
 
@@ -232,6 +243,9 @@ class ServeMetrics:
             "staged_early": self.counters["staged_early"],
             "step_compiles": self.counters["step_compiles"],
             "warmup_s": sum(self.warmup_s.values()),
+            "graph_s": self.counters["graph_s"],
+            "graph_edges": self.counters["graph_edges"],
+            "graph_edge_slots": self.counters["graph_edge_slots"],
             "queue_wait_p50_ms": percentile(self.queue_wait_s, 50) * 1e3,
             "queue_wait_p99_ms": percentile(self.queue_wait_s, 99) * 1e3,
             "latency_p50_ms": percentile(self.total_s, 50) * 1e3,

@@ -44,6 +44,14 @@ collectively is bisected into per-slot verdicts by re-evaluating masked
 sub-batches, so one degenerate geometry cannot poison its mates' results.
 Fault-injection points (`serve/faults.py`) thread through both halves of
 the step; they are no-ops unless a `FaultPlan` is installed.
+
+A model whose config declares ``max_neighbors`` (EquiformerV2) runs on a
+neighbour graph: each slot also stages ``nbr [max_atoms, k]`` and
+``nbr_mask``, the k nearest atoms within ``max_radius`` of each real atom,
+built on the host in float64 (`neighbour_graph`) when the slot is staged
+with geometry its graph was not built from, under a ``serve.graph`` span.
+Its step evaluates ``energy_graph``.  Any other model stages species,
+positions and mask, and its step evaluates ``energy_masked``.
 """
 from __future__ import annotations
 
@@ -58,7 +66,8 @@ from jax.profiler import TraceAnnotation
 
 from . import faults
 
-__all__ = ["BucketSpec", "SlotPool", "BucketedPools", "default_buckets"]
+__all__ = ["BucketSpec", "SlotPool", "BucketedPools", "default_buckets",
+           "neighbour_graph"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,6 +94,28 @@ def default_buckets(max_atoms: int, n_slots: int = 4,
     return tuple(
         BucketSpec(sz, n_slots, names.get(i + (3 - n), f"b{sz}"))
         for i, sz in enumerate(sizes))
+
+
+def neighbour_graph(pos, mask, cutoff: float, k: int):
+    """Each real atom's k nearest real atoms closer than ``cutoff``, in
+    float64, nearest first, ties broken by index; atoms with ``mask`` 0 get
+    no edges and are no one's neighbour.  pos [n, 3], mask [n] -> (nbr
+    [n, k] int32, the sources of each atom's edges; nbr_mask [n, k]
+    float32, 1 for a real edge).  A missing edge points at atom 0."""
+    pos = np.asarray(pos, np.float64)
+    real = np.asarray(mask) > 0
+    n = len(pos)
+    d = np.sqrt(np.sum(np.square(pos[None, :, :] - pos[:, None, :]), -1))
+    d[~(real[:, None] & real[None, :]) | np.eye(n, dtype=bool)] = np.inf
+    d[d >= cutoff] = np.inf
+    order = np.argsort(d, axis=1, kind="stable")[:, :k]
+    ok = np.isfinite(np.take_along_axis(d, order, axis=1))
+    nbr = np.zeros((n, k), np.int32)
+    nbr_mask = np.zeros((n, k), np.float32)
+    w = order.shape[1]
+    nbr[:, :w] = np.where(ok, order, 0)
+    nbr_mask[:, :w] = ok
+    return nbr, nbr_mask
 
 
 class _Inflight:
@@ -126,22 +157,35 @@ class SlotPool:
         self._cooldown_until = 0.0     # begin_step sits out until then
         self._failed_at = None         # first failure of the current outage
 
-        def batched(params, species, pos, mask):
+        cfg = getattr(model, "cfg", None)
+        self.k = getattr(cfg, "max_neighbors", None)
+        if self.k is None:
+            self.graph = None
+            energy = model.energy_masked
+        else:
+            self.cutoff = float(cfg.max_radius)
+            self.graph = (np.zeros((n_slots, max_atoms, self.k), np.int32),
+                          np.zeros((n_slots, max_atoms, self.k), np.float32))
+            self._graph_of = [None] * n_slots   # (pos, mask) it was built on
+            energy = model.energy_graph
+
+        def batched(params, species, pos, mask, *graph):
             """All slots in one call: vmapped masked energy + forces."""
-            def one(sp, p, m):
-                e, g = jax.value_and_grad(
-                    lambda pp: model.energy_masked(params, sp, pp, m))(p)
-                return e, -g
-            return jax.vmap(one)(species, pos, mask)
+            def one(sp, p, m, *g):
+                e, grad = jax.value_and_grad(
+                    lambda pp: energy(params, sp, pp, m, *g))(p)
+                return e, -grad
+            return jax.vmap(one)(species, pos, mask, *graph)
 
         # step inputs are fresh device buffers every step on accelerators
         # (donation consumes them, so the staged-tensor reuse below is a
         # CPU-only economy); on CPU nothing is donated and clean staged
         # tensors survive across steps
         self._donate = jax.default_backend() != "cpu"
-        donate = (1, 2, 3) if self._donate else ()
+        n_in = 3 if self.graph is None else 5
+        donate = tuple(range(1, 1 + n_in)) if self._donate else ()
         self._step_fn = jax.jit(batched, donate_argnums=donate)
-        self._staged = None          # (species_dev, pos_dev, mask_dev)
+        self._staged = None          # device copies of `_host_inputs()`
         self._dirty = True
 
     # ------------------------------------------------------------ queries
@@ -191,16 +235,48 @@ class SlotPool:
         return True
 
     # ------------------------------------------------------------ stepping
+    def _host_inputs(self, mask=None) -> tuple:
+        """The step's inputs after the weights, as host arrays: species,
+        positions, mask (``mask`` in place of the slots' own), and the
+        neighbour graph where the model runs on one."""
+        arrays = (self.species, self.pos, self.mask if mask is None else mask)
+        return arrays if self.graph is None else arrays + self.graph
+
+    def _build_graphs(self) -> None:
+        """Rebuild the graph of every slot whose positions or mask changed
+        since its graph was built (a freed slot's mask is all zero, so its
+        graph has no edges)."""
+        stale = [s for s, built in enumerate(self._graph_of)
+                 if built is None or not (np.array_equal(built[0], self.pos[s])
+                                          and np.array_equal(built[1], self.mask[s]))]
+        if not stale:
+            return
+        label = self.spec.label()
+        t0 = self.clock()
+        edges = 0
+        with TraceAnnotation("serve.graph", pool=label):
+            nbr, nbr_mask = self.graph
+            for s in stale:
+                nbr[s], nbr_mask[s] = neighbour_graph(self.pos[s], self.mask[s],
+                                                      self.cutoff, self.k)
+                self._graph_of[s] = (self.pos[s].copy(), self.mask[s].copy())
+                edges += int(nbr_mask[s].sum())
+        if self.metrics is not None:
+            self.metrics.observe_graph(self.clock() - t0, edges,
+                                       len(stale) * self.spec.max_atoms * self.k)
+
     def stage(self, early: bool = False) -> None:
         """Upload the slot arrays to the device if they changed since the
-        last upload.  Called with ``early=True`` from the pipelining overlap
-        window (another pool's step in flight) — counted so the overlap is
-        observable, not just asserted."""
+        last upload, after rebuilding the graphs that went stale.  Called
+        with ``early=True`` from the pipelining overlap window (another
+        pool's step in flight) — counted so the overlap is observable, not
+        just asserted."""
         if self._staged is not None and not self._dirty:
             return
+        if self.graph is not None:
+            self._build_graphs()
         with TraceAnnotation("serve.stage", pool=self.spec.label()):
-            self._staged = (jnp.asarray(self.species), jnp.asarray(self.pos),
-                            jnp.asarray(self.mask))
+            self._staged = tuple(jnp.asarray(a) for a in self._host_inputs())
         self._dirty = False
         if early and self.metrics is not None:
             self.metrics.observe_staged_early(self.spec.label())
@@ -216,10 +292,10 @@ class SlotPool:
             raise faults.InjectedFault(
                 f"injected compile failure in bucket {self.spec.label()}")
         self.stage()
-        sp, p, m = self._staged
+        staged = self._staged
         if self._donate:
             self._staged = None
-        jax.block_until_ready(self._step_fn(self.params, sp, p, m))
+        jax.block_until_ready(self._step_fn(self.params, *staged))
 
     def begin_step(self) -> Optional[_Inflight]:
         """Dispatch one fused evaluation of every active slot; returns an
@@ -237,14 +313,14 @@ class SlotPool:
             self._on_step_failure(active, "step_raised")
             return None
         self.stage()
-        sp, p, m = self._staged
+        staged = self._staged
         if self._donate:
             self._staged = None          # donated — never touch again
         t0 = self.clock()
         compiled = self._step_fn._cache_size()
         try:
             with TraceAnnotation("serve.dispatch", pool=self.spec.label()):
-                e, f = self._step_fn(self.params, sp, p, m)
+                e, f = self._step_fn(self.params, *staged)
         except Exception:
             self._on_step_failure(active, "step_raised")
             return None
@@ -379,8 +455,8 @@ class SlotPool:
             mask = np.zeros_like(self.mask)
             for i in group:
                 mask[i, :len(self.slot_req[i].species)] = 1.0
-            e, f = self._step_fn(self.params, jnp.asarray(self.species),
-                                 jnp.asarray(self.pos), jnp.asarray(mask))
+            e, f = self._step_fn(self.params, *(
+                jnp.asarray(a) for a in self._host_inputs(mask)))
             e, f = np.asarray(e), np.asarray(f)
             return {i: self._finite(e, f, i) for i in group}
 
