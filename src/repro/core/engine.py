@@ -72,7 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import constants
-from .irreps import l_array, num_coeffs
+from .irreps import num_coeffs
 
 __all__ = [
     "PlanKey",
@@ -142,8 +142,19 @@ def expand_degree_weights(w, L: int):
     """w [..., L+1] per-degree -> [..., (L+1)^2] packed broadcast.
 
     The canonical implementation (gaunt.py re-exports it for back-compat).
+    Built from per-degree broadcasts of ``w[..., l:l+1]`` to width 2l+1,
+    concatenated: the same values as the gather ``w[..., l_array(L)]``, but
+    its transpose is a slice-and-sum that XLA fuses.  The gather's transpose
+    is a scatter-add, which a force step (-dE/dpos through the per-edge
+    radial weights) would run as a serial while loop on the TPU.  ``take``,
+    fancy indexing and ``repeat`` with an array of repeats all lower to that
+    gather; a 0/1 [L+1, (L+1)^2] matmul costs several MXU passes at
+    ``highest`` precision.
     """
-    return w[..., jnp.asarray(l_array(L).astype(np.int32))]
+    lead = jnp.shape(w)[:-1]
+    return jnp.concatenate(
+        [jnp.broadcast_to(w[..., l:l + 1], lead + (2 * l + 1,))
+         for l in range(L + 1)], axis=-1)
 
 
 def _wmul(x, w, L: int):

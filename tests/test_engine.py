@@ -464,3 +464,51 @@ def test_failing_autotune_candidate_is_recorded(monkeypatch, site):
     assert "refused by the compiler" in rec["error"]
     eng.clear()
     assert eng.autotune_failures == []
+
+
+# --------------------------------------------------------------------------
+# expand_degree_weights: per-degree broadcasts, no gather and no scatter
+# --------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("lead", [(), (3,), (2, 3, 5)])
+@pytest.mark.parametrize("L", range(7))
+def test_expand_degree_weights_equals_gather(L, lead):
+    from repro.core.irreps import l_array
+
+    w = _rand(lead + (L + 1,), 20 + L)
+    got = engine.expand_degree_weights(w, L)
+    ref = w[..., jnp.asarray(l_array(L).astype(np.int32))]
+    assert got.shape == lead + (num_coeffs(L),)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+@pytest.mark.parametrize("L", range(7))
+def test_expand_degree_weights_vjp_sums_each_degree(L):
+    w = _rand((4, L + 1), 30 + L)
+    ct = _rand((4, num_coeffs(L)), 40 + L)
+    _, vjp = jax.vjp(lambda v: engine.expand_degree_weights(v, L), w)
+    (got,) = vjp(ct)
+    ref = np.stack([np.asarray(ct)[:, l * l:(l + 1) ** 2].sum(-1)
+                    for l in range(L + 1)], axis=-1)
+    np.testing.assert_allclose(np.asarray(got), ref, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("L", [1, 2])
+def test_mace_force_backward_has_no_scatter(L):
+    """Forces take the transpose of the per-edge radial weights' expansion;
+    a gather there would leave a scatter-add (a serial loop on the TPU)."""
+    from repro.configs.gaunt_ff import EquivariantConfig
+    from repro.models.equivariant import MaceGaunt
+
+    cfg = EquivariantConfig(name="t", kind="mace", L=L, L_edge=3, channels=8,
+                            n_layers=2, nu=3, n_species=4, hidden=16,
+                            conv_impl="escn")
+    model = MaceGaunt(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    n = 12
+    species = jnp.arange(n) % cfg.n_species
+    pos = _rand((n, 3), 50) * 2.0
+    hlo = (jax.jit(jax.grad(model.energy_masked, argnums=2))
+           .lower(params, species, pos, jnp.ones(n)).compile().as_text())
+    assert " scatter(" not in hlo
