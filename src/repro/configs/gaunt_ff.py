@@ -48,7 +48,7 @@ class EquivariantConfig:
     compute_dtype: str = "float32"
     # persistent autotune cache file (DESIGN.md §4.5): serve warmup() points
     # the engine at this path so measured selections (backends, chain
-    # flavors, dtype winners, fused calibration) load from disk instead of
+    # flavors, dtype winners) load from disk instead of
     # re-timing — a warm host boots with zero timing runs.  None (default)
     # falls back to $REPRO_AUTOTUNE_CACHE, else persistence stays off.
     # Pre-populate with `python -m repro.core.autotune_cache --cache <path>`.

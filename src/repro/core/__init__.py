@@ -4,7 +4,7 @@ Public API:
     GauntEngine / plan      unified plan/dispatch layer over all backends
     plan_chain / ChainPlan  whole chained products, Fourier-resident interior
     autotune_cache          persistent per-host measured-selection cache
-                            (fingerprinted JSON + offline calibrate CLI)
+                            (fingerprinted JSON + offline warm-up CLI)
     Rep                     basis-tagged activations (sh | fourier residency)
     GauntTensorProduct      full O(L^3) tensor product (FFT / direct / packed)
     EquivariantConv         x (x) Y(rhat) with the eSCN-sparsity fast path

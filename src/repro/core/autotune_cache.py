@@ -4,21 +4,22 @@ The measured autotuner (engine.py, ``tune='measure'``) is the arbiter of
 every hot-path choice — backend per plan key, chain flavor per chain key,
 storage dtype per 'auto' key family — but its selection table lives
 in-process, so every serve process re-times every key at startup.  This
-module persists the three measurement stores to ONE versioned JSON file per
+module persists the two measurement stores to ONE versioned JSON file per
 host so selections are measured once and reused:
 
     selections   engine._measured    {PlanKey -> backend | chain backend |
                                        dtype winner ('auto' keys)}
     timings      engine._measured_t  {PlanKey -> best wall seconds}
-    calibration  engine._CALIB       the fused-cost skinny-matmul factors
 
 File format (schema-versioned, human-inspectable):
 
     {"fingerprint": {schema, backend, device_kind, device_count,
                      jax_version, x64},
      "selections": [{"key": {...PlanKey fields...}, "backend": "...",
-                     "t": seconds | null}, ...],
-     "calibration": {... engine.get_calibration() ...}}
+                     "t": seconds | null}, ...]}
+
+Members the loader does not know (a ``calibration`` member written by older
+revisions) are ignored.
 
 Trust rules — a persisted entry is only as good as the measurement that
 produced it:
@@ -55,11 +56,12 @@ Offline pre-population::
     python -m repro.core.autotune_cache --cache /var/cache/gaunt.json
     python -m repro.core.autotune_cache --cache ... --verify-warm  # 0 runs?
 
-sweeps the known workload grid (the benchmark pairwise/conv/chain keys at
-both storage precisions plus the 'auto' families, the serve selfmix chain
-keys — ungated, gate-fused, and the ``grid_gate='auto'`` policy family —
-and ``calibrate_fused`` per dtype) so production processes boot with a
-fully warm selection table.  ``scripts/calibrate.py`` is a thin wrapper.
+sweeps a known workload grid (pairwise/conv/chain keys at both storage
+precisions plus the 'auto' families, and the serve selfmix chain keys —
+ungated, gate-fused, and the ``grid_gate='auto'`` policy family) so
+processes that tune with ``tune='measure'`` boot with a fully warm
+selection table.  It warms only the measured autotuner: the heuristic cost
+model reads no file.
 """
 from __future__ import annotations
 
@@ -168,7 +170,7 @@ def _entry_valid(key, backend: str) -> bool:
 
 
 def load(path: str | None):
-    """-> (selections, timings, calibration) or None.
+    """-> (selections, timings) or None.
 
     None means "no usable cache": missing file, unreadable/corrupt JSON,
     wrong schema, or a fingerprint mismatch — all fall back to in-process
@@ -196,12 +198,11 @@ def load(path: str | None):
         t = ent.get("t")
         if isinstance(t, (int, float)):
             timings[key] = float(t)
-    calib = raw.get("calibration")
-    return selections, timings, dict(calib) if isinstance(calib, dict) else {}
+    return selections, timings
 
 
 def save(path: str, selections: dict, timings: dict,
-         calibration: dict | None = None, merge: bool = True) -> None:
+         merge: bool = True) -> None:
     """Atomically persist the measurement stores to ``path``.
 
     With ``merge`` (the default) a valid same-fingerprint file already at
@@ -226,8 +227,6 @@ def save(path: str, selections: dict, timings: dict,
             for k, b in selections.items()
         ],
     }
-    if calibration is not None:
-        payload["calibration"] = dict(calibration)
     d = os.path.dirname(os.path.abspath(path))
     os.makedirs(d, exist_ok=True)
     fd, tmp = tempfile.mkstemp(prefix=".gaunt_autotune.", suffix=".json", dir=d)
@@ -243,52 +242,21 @@ def save(path: str, selections: dict, timings: dict,
         raise
 
 
-def merge_calibration(saved: dict) -> int:
-    """Fold persisted calibration into the process, without clobbering
-    constants this process measured itself (in-process is fresher).  Only
-    entries the file marks ``*_measured`` are applied — an inherited default
-    in the file must not masquerade as a measurement here.  Returns the
-    number of factors applied."""
-    from .engine import get_calibration, set_calibration
-
-    cur = get_calibration()
-    apply = {}
-    for base in [k for k in cur if not k.endswith("_measured")]:
-        mk = base + "_measured"
-        if saved.get(mk) and not cur.get(mk) \
-                and isinstance(saved.get(base), (int, float)):
-            apply[base] = float(saved[base])
-            apply[mk] = True
-    if apply:
-        set_calibration(**apply)
-    return len(apply) // 2
-
-
 # --------------------------------------------------------------------------
-# offline calibrate CLI
+# offline warm-up CLI
 # --------------------------------------------------------------------------
 
 
 def _sweep(eng, fast: bool, serve_rows: tuple = (1024,)) -> int:
     """Measure the known workload grid into ``eng``'s selection table.
 
-    Mirrors the benchmark sweep (bench_engine.run / run_chain_kernel /
-    run_mixed_precision) plus the serve warmup's selfmix chain keys, at both
-    storage precisions and the 'auto' family, so a production process that
-    loads the resulting file boots with zero timing runs.
+    Pairwise, conv and chain keys plus the serve warmup's selfmix chain
+    keys, at both storage precisions and the 'auto' family, so a process
+    that loads the resulting file boots with zero timing runs.
     """
-    from .engine import _calib_key, get_calibration
-
     n0 = len(eng._measured)
     dtypes = ("float32", "bfloat16", "auto")
-    # fused-cost calibration per storage dtype (feeds heuristic rankings);
-    # a persisted cache that already carries a measured factor for this
-    # dtype covers it — calibrate_fused always times, so re-running it on a
-    # warm host would break the zero-timing-runs contract for no new signal
-    for d in ("float32", "bfloat16"):
-        if not get_calibration().get(_calib_key(d) + "_measured"):
-            eng.calibrate_fused(dtype=d)
-    # pairwise + conv_filter plan keys (the bench grid)
+    # pairwise + conv_filter plan keys
     L_list = (1, 2, 3, 6) if fast else (1, 2, 3, 4, 6)
     B_list = (64, 1024)
     for L in L_list:
@@ -298,7 +266,7 @@ def _sweep(eng, fast: bool, serve_rows: tuple = (1024,)) -> int:
                          requires_grad=False)
         eng.plan(L, L, L, kind="conv_filter", batch_hint=B_list[-1],
                  tune="measure", requires_grad=False)
-    # chained workloads (the bench chain-kernel grid)
+    # chained workloads
     chains = [
         ((1, 1, 1), 1, 512),
         ((2, 2), 2, 64),
@@ -337,9 +305,9 @@ def main(argv=None) -> int:
 
     ap = argparse.ArgumentParser(
         prog="python -m repro.core.autotune_cache",
-        description="Offline autotune calibration: sweep the known workload "
+        description="Offline autotune warm-up: sweep the known workload "
                     "grid and persist the measured selection table so "
-                    "production processes boot warm.")
+                    "processes that tune by measurement boot warm.")
     ap.add_argument("--cache", default=None,
                     help=f"cache file (default: ${ENV_VAR} or "
                          f"{default_path()})")

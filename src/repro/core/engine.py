@@ -86,9 +86,6 @@ __all__ = [
     "GauntEngine",
     "register_backend",
     "available_backends",
-    "get_calibration",
-    "set_calibration",
-    "reset_calibration",
     "spectral_default",
     "expand_degree_weights",
     "get_engine",
@@ -1273,73 +1270,14 @@ def _constrained_chain_apply(apply: Callable, mesh, dp: tuple) -> Callable:
 
 
 # --------------------------------------------------------------------------
-# cost model (relative real-MAC counts; calibrated coarsely, see DESIGN.md §4;
-# the fused skinny-matmul factor is *measured* — `GauntEngine.calibrate_fused`)
+# cost model (relative real-MAC counts, set by hand; see DESIGN.md §4)
 # --------------------------------------------------------------------------
 
 _C_CPLX = 4.0        # complex MAC = 4 real MACs
+_C_SKINNY = 4.0      # skinny collocation matmuls (G >> d) are memory-bound
 _C_FFT = 10.0        # per point per log2 level: tiny-grid FFTs vectorize poorly
 _OVERHEAD = 3e4      # per dispatched op: favors fewer, denser ops at small sizes
 _INTERPRET_PENALTY = 1e4   # Pallas interpret mode off-TPU is not a real option
-
-# Measured calibration constants feeding the heuristic cost model.
-# 'fused_skinny' scales the collocation backends' per-element cost: their
-# matmuls are skinny (G >> d, memory-bound) while dense_einsum is one
-# well-blocked contraction, so wall time sits a constant factor off the raw
-# MAC ratio.  The default 4.0 is the historical CPU-era magic number;
-# `GauntEngine.calibrate_fused()` replaces it with a value measured on THIS
-# host/backend (benchmarks run it and record the result in BENCH_gaunt.json),
-# so heuristic-mode plans stop inheriting another machine's constant.
-#
-# Calibration is keyed BY STORAGE DTYPE: bf16 skinny matmuls have a different
-# matmul/bandwidth ratio than f32 (half the bytes, same MXU issue), so one
-# dtype-agnostic factor would skew the other precisions' rankings.  The bare
-# 'fused_skinny' key is the float32 entry (back-compat); other dtypes live at
-# 'fused_skinny:<dtype>' and inherit the float32 value until measured
-# (``None`` = inherit).
-_CALIB = {
-    "fused_skinny": 4.0, "fused_skinny_measured": False,
-    "fused_skinny:bfloat16": None, "fused_skinny:bfloat16_measured": False,
-    "fused_skinny:float64": None, "fused_skinny:float64_measured": False,
-}
-# pristine copy for reset_calibration(): _CALIB is module-global mutable
-# state, so without a reset a calibrate_fused() run in one engine/test
-# silently skews heuristic rankings in every other
-_CALIB_DEFAULTS = dict(_CALIB)
-
-
-def _calib_key(dtype: str) -> str:
-    return "fused_skinny" if dtype == "float32" else f"fused_skinny:{dtype}"
-
-
-def _calib_factor(dtype: str) -> float:
-    v = _CALIB.get(_calib_key(dtype))
-    return _CALIB["fused_skinny"] if v is None else v
-
-
-def get_calibration() -> dict:
-    """The cost model's calibration constants (see `_CALIB`)."""
-    return dict(_CALIB)
-
-
-def set_calibration(**kw) -> None:
-    """Override calibration constants (tests / cross-host replay).
-
-    Per-dtype entries use the key 'fused_skinny:<dtype>' — pass them via
-    dict-splat (the ':' is not a valid identifier character).
-    """
-    unknown = set(kw) - set(_CALIB)
-    if unknown:
-        raise ValueError(f"unknown calibration constants {sorted(unknown)}")
-    _CALIB.update(kw)
-
-
-def reset_calibration() -> None:
-    """Restore the default calibration constants and drop all ``*_measured``
-    flags — wired into ``GauntEngine.clear()`` so two fresh engines always
-    rank backends identically regardless of what a previous engine measured."""
-    _CALIB.clear()
-    _CALIB.update(_CALIB_DEFAULTS)
 
 
 def _dims(key: PlanKey):
@@ -1432,13 +1370,9 @@ def _cost_fused(key: PlanKey, pallas: bool) -> float:
     B, d1, d2, do, n1, n2, N = _dims(key)
     Nf = 2 * (key.L1 + key.L2) + 2
     G = ((Nf * Nf + 127) // 128) * 128
-    # the skinny-matmul factor is a *measured*, per-dtype calibration
-    # constant (GauntEngine.calibrate_fused, recorded in BENCH_gaunt.json);
-    # 4.0 is only the never-calibrated default
-    f = _calib_factor(key.dtype)
-    c = f * B * G * (d1 + d2 + do) + _OVERHEAD * 4
+    c = _C_SKINNY * B * G * (d1 + d2 + do) + _OVERHEAD * 4
     if key.kind == "channel_mix":
-        c = 4.0 * f * B * G * (d1 + d2 + do) + _OVERHEAD * 4
+        c = 4.0 * _C_SKINNY * B * G * (d1 + d2 + do) + _OVERHEAD * 4
     if pallas:
         c *= 0.5 if jax.default_backend() == "tpu" else _INTERPRET_PENALTY
     return c
@@ -1780,7 +1714,7 @@ class GauntEngine:
         self._cache_path = cache_path
         self._cache_loaded = False
         # counts timed measurement passes (plan backends, chain candidates,
-        # fused calibration).  A process booted against a warm cache must
+        # dtype siblings).  A process booted against a warm cache must
         # keep this at 0 — the warm-start acceptance proof and the CLI's
         # --verify-warm both read it.
         self.timing_runs = 0
@@ -1805,7 +1739,7 @@ class GauntEngine:
         return _ac.resolve_path(self._cache_path)
 
     def load_autotune_cache(self) -> int:
-        """Load persisted selections/timings/calibration now (idempotent;
+        """Load persisted selections and timings now (idempotent;
         in-process entries win over the file's).  -> #selections adopted."""
         self._cache_loaded = True
         path = self._resolved_cache_path()
@@ -1816,7 +1750,7 @@ class GauntEngine:
         data = _ac.load(path)
         if data is None:
             return 0
-        selections, timings, calib = data
+        selections, timings = data
         n = 0
         for k, b in selections.items():
             if k not in self._measured:
@@ -1824,7 +1758,6 @@ class GauntEngine:
                 n += 1
         for k, t in timings.items():
             self._measured_t.setdefault(k, t)
-        _ac.merge_calibration(calib)
         return n
 
     def _maybe_load_cache(self) -> None:
@@ -1839,8 +1772,7 @@ class GauntEngine:
             return None
         from . import autotune_cache as _ac
 
-        _ac.save(path, self._measured, self._measured_t,
-                 calibration=get_calibration())
+        _ac.save(path, self._measured, self._measured_t)
         return path
 
     def _autoflush(self) -> None:
@@ -2486,48 +2418,6 @@ class GauntEngine:
             self._autoflush()
         return winner
 
-    def calibrate_fused(self, L: int = 6, B: int = 64,
-                        dtype: str = "float32") -> dict:
-        """Measure the fused cost model's skinny-matmul factor on THIS host.
-
-        Times the `fused_xla` collocation and the `dense_einsum` baseline on
-        one reference pairwise workload, infers the per-MAC cost ratio the
-        heuristic needs to rank them consistently with measurement, installs
-        it under the *per-dtype* calibration key ('fused_skinny' for f32,
-        'fused_skinny:<dtype>' otherwise — bf16's matmul/bandwidth ratio
-        must not skew the f32 ranking and vice versa), and returns the
-        record (benchmarks write it to BENCH_gaunt.json).
-        """
-        dts = _dtype_str(dtype)
-        key = PlanKey(L, L, L, kind="pairwise", batch_hint=B, dtype=dts)
-        args = _synthetic_inputs(key)
-        self.timing_runs += 1
-        times = {}
-        for name in ("fused_xla", "dense_einsum"):
-            apply = _REGISTRY[name].build(key)
-            fn = jax.jit(lambda *a: apply(*a))
-            jax.block_until_ready(fn(*args))
-            ts = []
-            for _ in range(5):
-                t0 = time.perf_counter()
-                jax.block_until_ready(fn(*args))
-                ts.append(time.perf_counter() - t0)
-            times[name] = sorted(ts)[len(ts) // 2]
-        d = num_coeffs(L)
-        G = ((2 * (2 * L) + 2) ** 2 + 127) // 128 * 128
-        macs_fused = B * G * (3 * d)
-        macs_dense = B * d * d * d
-        factor = (times["fused_xla"] / macs_fused) / \
-            (times["dense_einsum"] / macs_dense)
-        factor = float(min(16.0, max(0.25, factor)))
-        ck = _calib_key(dts)
-        set_calibration(**{ck: factor, ck + "_measured": True})
-        self._autoflush()
-        return {"factor": round(factor, 3),
-                "fused_xla_us": round(times["fused_xla"] * 1e6, 1),
-                "dense_einsum_us": round(times["dense_einsum"] * 1e6, 1),
-                "L": L, "B": B, "dtype": dts}
-
     def select(self, key: PlanKey, tune: str = "heuristic",
                requires_grad: bool = True) -> str:
         """Pick the backend for ``key`` by cost model or measurement."""
@@ -2563,10 +2453,8 @@ class GauntEngine:
         self._chains.clear()
         self._measured.clear()
         self._measured_t.clear()
-        # a cleared engine must behave like a fresh one: calibration is
-        # module-global (shared by every engine's cost model), so restore
-        # the defaults too, and re-arm the lazy persistent-cache load
-        reset_calibration()
+        # a cleared engine must behave like a fresh one: re-arm the lazy
+        # persistent-cache load
         self._cache_loaded = False
         self.timing_runs = 0
         self.autotune_failures.clear()

@@ -102,7 +102,7 @@ class Scheduler:
 
     ``run(requests)`` is the closed-loop entry (submit everything, drain);
     open-loop load generators submit as arrivals happen and call ``pump()``
-    per iteration (benchmarks/bench_serve.py).
+    per iteration (``bench/harness/serve.py``).
     """
 
     def __init__(self, engine, clock=time.monotonic, metrics=None):

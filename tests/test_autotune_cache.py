@@ -67,33 +67,47 @@ def test_load_is_lazy_and_in_process_wins(tmp_path):
     assert p.backend == local_pick
 
 
-def test_calibration_roundtrips_without_masquerading(tmp_path):
-    """Persisted fused-cost factors apply on load — but only entries the
-    file marks *_measured, and never over a locally measured value."""
+def test_file_with_calibration_member_still_loads(tmp_path):
+    """Older revisions also wrote a ``calibration`` member; a file carrying
+    one loads with the member ignored and warms the engine all the same."""
     path = str(tmp_path / "cache.json")
-    base = engine.get_calibration()
+    cold = engine.GauntEngine(cache_path=path)
+    _measure_some(cold)
+    raw = json.load(open(path))
+    raw["calibration"] = {"fused_skinny": 9.5, "fused_skinny_measured": True}
+    json.dump(raw, open(path, "w"))
+
+    sel, tim = ac.load(path)
+    assert sel == cold._measured and tim == cold._measured_t
+    warm = engine.GauntEngine(cache_path=path)
+    _measure_some(warm)
+    assert warm.timing_runs == 0
+
+
+def test_cli_round_trip_verify_warm(tmp_path, monkeypatch, capsys):
+    """The operator's warm-up: a first ``main`` measures and persists the
+    sweep, a second with ``--verify-warm`` answers it from the file with
+    zero timing runs.  The sweep is stubbed to one key (the real ``--fast``
+    grid takes most of a minute on a CPU)."""
+    def one_key(eng, fast, serve_rows=(1024,)):
+        n0 = len(eng._measured)
+        eng.plan(1, 1, 2, batch_hint=32, tune="measure", requires_grad=False)
+        return len(eng._measured) - n0
+
+    monkeypatch.setattr(ac, "_sweep", one_key)
+    path = str(tmp_path / "cli_cache.json")
+    eng = engine.get_engine()
     try:
-        cold = engine.GauntEngine(cache_path=path)
-        rec = cold.calibrate_fused(L=2, B=32)
-        cold.flush_autotune_cache()
-
-        engine.reset_calibration()
-        warm = engine.GauntEngine(cache_path=path)
-        warm.load_autotune_cache()
-        cal = engine.get_calibration()
-        assert cal["fused_skinny_measured"]
-        assert cal["fused_skinny"] == pytest.approx(rec["factor"], rel=1e-2)
-        # the file's unmeasured per-dtype defaults were NOT applied as real
-        assert not cal["fused_skinny:float64_measured"]
-
-        # a locally measured value survives a load of a stale file
-        engine.reset_calibration()
-        engine.set_calibration(fused_skinny=9.5, fused_skinny_measured=True)
-        warm2 = engine.GauntEngine(cache_path=path)
-        warm2.load_autotune_cache()
-        assert engine.get_calibration()["fused_skinny"] == 9.5
+        eng.clear()
+        assert ac.main(["--fast", "--cache", path]) == 0
+        assert eng.timing_runs > 0 and ac.load(path)[0]
+        eng.clear()   # a fresh process: nothing measured in memory
+        assert ac.main(["--fast", "--cache", path, "--verify-warm"]) == 0
+        assert eng.timing_runs == 0
+        assert "verify-warm OK: zero timing runs" in capsys.readouterr().out
     finally:
-        engine.set_calibration(**base)
+        eng.set_autotune_cache(None)
+        eng.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -175,12 +189,12 @@ def test_save_merges_concurrent_same_fingerprint_entries(tmp_path):
     kb = engine.PlanKey(2, 2, 4, kind="pairwise", batch_hint=8)
     ac.save(path, {ka: "fft"}, {ka: 1.0})
     ac.save(path, {kb: "direct"}, {kb: 2.0})  # a "concurrent" process
-    sel, tim, _ = ac.load(path)
+    sel, tim = ac.load(path)
     assert sel == {ka: "fft", kb: "direct"}
     assert tim == {ka: 1.0, kb: 2.0}
     # collision: the flushing process's own entry wins
     ac.save(path, {ka: "dense_einsum"}, {ka: 0.5})
-    sel, tim, _ = ac.load(path)
+    sel, tim = ac.load(path)
     assert sel[ka] == "dense_einsum" and tim[ka] == 0.5
 
 

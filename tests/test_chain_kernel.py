@@ -561,31 +561,6 @@ def test_chain_autotune_result_matches_tree():
                                rtol=1e-4, atol=1e-4)
 
 
-def test_fused_cost_calibration():
-    """The skinny-matmul factor is a calibration constant, not a literal:
-    measured installs override the default and the fused cost moves with it."""
-    from repro.core.engine import (PlanKey, _cost_fused, get_calibration,
-                                   set_calibration)
-
-    base = get_calibration()
-    try:
-        key = PlanKey(4, 4, 4, kind="pairwise", batch_hint=256)
-        set_calibration(fused_skinny=2.0, fused_skinny_measured=True)
-        c2 = _cost_fused(key, pallas=False)
-        set_calibration(fused_skinny=8.0)
-        c8 = _cost_fused(key, pallas=False)
-        assert c8 > c2
-        with pytest.raises(ValueError):
-            set_calibration(nonsense=1.0)
-    finally:
-        set_calibration(**base)
-    # the measuring entry point installs a sane factor and reports it
-    eng = engine.get_engine()
-    rec = eng.calibrate_fused(L=2, B=32)
-    assert 0.25 <= rec["factor"] <= 16.0
-    assert get_calibration()["fused_skinny_measured"]
-
-
 # --------------------------------------------------------------------------
 # sharded chains: ragged rows pad/slice over the device count
 # --------------------------------------------------------------------------

@@ -1,6 +1,9 @@
 """The unified Gaunt engine: cross-backend equivalence against the complex128
 numpy oracle, plan/constant caching, capability filtering, and autotune."""
 import dataclasses
+import importlib.util
+import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -235,7 +238,7 @@ def test_jit_containing_plan_and_apply():
 
 
 # ---------------------------------------------------------------------------
-# mixed precision: storage/accumulation split, dtype='auto', per-dtype calib
+# mixed precision: storage/accumulation split, dtype='auto'
 # ---------------------------------------------------------------------------
 
 
@@ -285,32 +288,6 @@ def test_chain_dtype_auto_measures_and_caches():
 
     assert_close(np.asarray(cp.apply([x, x])).astype(np.float64),
                  np.asarray(ref), dtype=cp.dtype, tier="identity")
-
-
-def test_calibration_is_keyed_by_dtype():
-    """Satellite: calibrate_fused(dtype=...) installs a per-dtype factor and
-    leaves the other precisions' entries untouched."""
-    from repro.core.engine import get_calibration, set_calibration
-
-    base = get_calibration()
-    eng = engine.GauntEngine()
-    try:
-        rec = eng.calibrate_fused(L=2, B=32, dtype="bfloat16")
-        assert rec["dtype"] == "bfloat16"
-        cal = get_calibration()
-        assert cal["fused_skinny:bfloat16_measured"]
-        assert cal["fused_skinny:bfloat16"] == pytest.approx(rec["factor"],
-                                                             rel=1e-2)
-        # the f32 entry did not move
-        assert cal["fused_skinny"] == base["fused_skinny"]
-        assert cal["fused_skinny_measured"] == base["fused_skinny_measured"]
-        # cost model reads the per-dtype factor
-        kf = engine.PlanKey(4, 4, 4, kind="pairwise", batch_hint=256)
-        kb = kf.with_dtype("bfloat16")
-        set_calibration(**{"fused_skinny": 2.0, "fused_skinny:bfloat16": 8.0})
-        assert engine._cost_fused(kb, pallas=False) > engine._cost_fused(kf, pallas=False)
-    finally:
-        set_calibration(**{k: v for k, v in base.items()})
 
 
 def test_plan_batch_buckets_key_on_storage_dtype():
@@ -379,29 +356,6 @@ def test_auto_key_family_across_clear():
     p2 = eng.plan(1, 1, 2, dtype="auto", tune="measure", batch_hint=16,
                   requires_grad=False)
     assert eng._measured[fam] == p2.key.dtype
-
-
-def test_clear_resets_calibration_so_fresh_engines_rank_identically():
-    """Satellite: _CALIB is module-global — clear() must restore defaults so
-    a calibrate_fused() run in one engine cannot skew another's rankings."""
-    from repro.core.engine import (get_calibration, reset_calibration,
-                                   set_calibration)
-
-    base = get_calibration()
-    try:
-        reset_calibration()
-        defaults = get_calibration()
-        k = engine.PlanKey(6, 6, 6, kind="pairwise", batch_hint=64)
-        pick_fresh = engine.GauntEngine().select(k)
-        # a "measured" calibration from some other engine skews the model...
-        set_calibration(fused_skinny=16.0, fused_skinny_measured=True)
-        assert get_calibration() != defaults
-        # ...until any engine's clear() restores the defaults
-        engine.GauntEngine().clear()
-        assert get_calibration() == defaults
-        assert engine.GauntEngine().select(k) == pick_fresh
-    finally:
-        set_calibration(**base)
 
 
 # --------------------------------------------------------------------------
@@ -512,3 +466,90 @@ def test_mace_force_backward_has_no_scatter(L):
     hlo = (jax.jit(jax.grad(model.energy_masked, argnums=2))
            .lower(params, species, pos, jnp.ones(n)).compile().as_text())
     assert " scatter(" not in hlo
+
+
+# --------------------------------------------------------------------------
+# the plans each benchmarked configuration serves with, pinned
+# --------------------------------------------------------------------------
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# What the engine picks for every plan key one served step (vmapped energy +
+# forces over slots) builds at each configuration of BENCHMARK.json: the
+# backend the step's plans were built with, the cost model's own pick for
+# that key when no backend is forced, and each chain's flavour.  Under
+# heuristic tuning no key carries a batch hint, so the slot shape does not
+# enter them.  A change to the cost model or the backend registry that moves
+# a pick shows here before it reaches the chip.
+SERVED_PICKS = {
+    "mace-3bpa": {
+        "conv_filter (2, 3)->2": ("escn_aligned", "dense_einsum"),
+        "conv_filter (2, 3)->2 geometry=wigner": ("escn_aligned",
+                                                  "escn_aligned"),
+        "chain (2, 2, 2)->2": "tree conversion=half conv=rfft",
+    },
+    "mace-off23-medium": {
+        "conv_filter (1, 3)->1": ("escn_aligned", "dense_einsum"),
+        "conv_filter (1, 3)->1 geometry=wigner": ("escn_aligned",
+                                                  "escn_aligned"),
+        "chain (1, 1, 1)->1": "tree conversion=half conv=rfft",
+    },
+    "eqv2-l6m2-selfmix": {
+        "chain (6, 6)->6": "tree conversion=half conv=rfft",
+    },
+}
+
+
+def _served_picks(name: str) -> dict:
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        path = {c["name"]: c["file"] for c in json.load(f)["configs"]}[name]
+    with open(os.path.join(ROOT, path)) as f:
+        config = json.load(f)
+    family = config["family"]
+    spec = importlib.util.spec_from_file_location(
+        f"{family}_program_t", os.path.join(ROOT, "bench", "programs",
+                                            f"{family}.py"))
+    program = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(program)
+
+    eng = engine.get_engine()
+    eng.clear()
+    model = program.build(config)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    k = config["model"].get("max_neighbors")
+    energy = model.energy_masked if k is None else model.energy_graph
+
+    def step(params, *inputs):
+        def one(sp, p, m, *g):
+            return jax.value_and_grad(
+                lambda q: energy(params, sp, q, m, *g))(p)
+        return jax.vmap(one)(*inputs)
+
+    slots, atoms = 2, 8
+    inputs = [jax.ShapeDtypeStruct((slots, atoms), jnp.int32),
+              jax.ShapeDtypeStruct((slots, atoms, 3), jnp.float32),
+              jax.ShapeDtypeStruct((slots, atoms), jnp.float32)]
+    if k is not None:
+        inputs += [jax.ShapeDtypeStruct((slots, atoms, k), jnp.int32),
+                   jax.ShapeDtypeStruct((slots, atoms, k), jnp.float32)]
+    with jax.default_matmul_precision(config["precision"]["matmul"]):
+        jax.eval_shape(step, params, *inputs)
+
+    picks = {}
+    for p in eng.plans():
+        key = p.key
+        assert key.batch_hint is None and key.dtype == "float32"
+        opts = "".join(f" {o}={v}" for o, v in key.extra)
+        picks[f"{key.kind} ({key.L1}, {key.L2})->{key.Lout}{opts}"] = (
+            p.backend, eng.select(key))
+    for c in eng._chains.values():
+        assert c.dtype == "float32" and not c.gate
+        picks[f"chain {tuple(c.Ls)}->{c.Lout}"] = (
+            f"{c.backend} conversion={c.conversion} conv={c.conv}")
+    eng.clear()
+    return picks
+
+
+@pytest.mark.parametrize("name", sorted(SERVED_PICKS))
+def test_served_plans_are_pinned(name):
+    assert _served_picks(name) == SERVED_PICKS[name]

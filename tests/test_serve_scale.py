@@ -7,6 +7,7 @@ import dataclasses
 import os
 import subprocess
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -142,6 +143,47 @@ def test_overlap_admission_stages_early(small_model):
     assert eng.metrics.counters["staged_early"] >= 1
     e = _direct_energy(model, params, small)
     assert abs(small.energy - e) < 1e-4 * max(1.0, abs(e))
+
+
+def test_open_loop_smoke_completes_every_request(small_model):
+    """A mixed-size stream of 12 requests arriving at 15 req/s on the wall
+    clock, admitted mid-flight across a three-bucket ladder: every request
+    is answered, none is rejected, and a warm engine times nothing while
+    serving."""
+    from repro.core.engine import get_engine
+
+    model, params = small_model
+    eng = EquivariantServeEngine(model, params,
+                                 buckets=[(6, 2), (12, 2), (24, 2)])
+    eng.warmup()
+    rng = np.random.default_rng(0)
+    sizes = [2, 5, 9, 3, 14, 6, 11, 4, 20, 7, 2, 12]
+    reqs = [EquivariantRequest(species=rng.integers(0, 4, n),
+                               pos=(rng.normal(size=(n, 3)) * 1.5)
+                               .astype(np.float32), rid=i)
+            for i, n in enumerate(sizes)]
+    sched = Scheduler(eng)
+    due = [i / 15.0 for i in range(len(reqs))]
+    runs0 = get_engine().timing_runs
+    t0 = time.monotonic()
+    i = 0
+
+    def feed():
+        nonlocal i
+        while i < len(reqs) and due[i] <= time.monotonic() - t0:
+            sched.submit(reqs[i])
+            i += 1
+
+    while True:
+        feed()
+        if not sched.pump(poll=feed) and i >= len(reqs):
+            break
+        if not eng.has_active() and not len(sched.queue) and i < len(reqs):
+            time.sleep(0.002)
+    m = eng.metrics.summary()
+    assert m["completed"] == 12 and m["rejected"] == 0, m
+    assert all(r.done and not r.rejected for r in reqs)
+    assert get_engine().timing_runs == runs0
 
 
 def test_deadline_holds_in_real_engine(small_model):
