@@ -33,12 +33,14 @@ channels; at m>0 ``y+ = W_r x+ - W_i x-``, ``y- = W_r x- + W_i x+``.
 the S^2 grid.  ``LN_sh`` is a LayerNorm at l=0 and, at l>0, a division by
 the RMS over channels and coefficients (each degree weighted 1/(2l+1)) with
 a per-(l, channel) scale.  Selfmix is `SelfmixLayer.interaction`:
-``mix(P_L[(w1 . x)(s) (w2 . x)(s)])``, a Gaunt self-product through the
-chain engine, under its own LN_sh like the block's other two sublayers (a
-quadratic residual update on unnormalised features would grow without
-bound over the blocks).  The blocks run as one ``lax.scan`` over stacked block
-parameters, each block under ``jax.checkpoint``: the force backward then
-keeps one block's edge activations at a time.
+``mix(P_L[(w1 . x)(s) (w2 . x)(s)])``, a Gaunt self-product computed on
+the smallest sphere quadrature exact for it, 10 x 19 at L = 6
+(``tp_impl='quad'``: sample, multiply, project, three matmuls), under its
+own LN_sh like the block's other two sublayers (a quadratic residual update
+on unnormalised features would grow without bound over the blocks).  The
+blocks run as one ``lax.scan`` over stacked block parameters, each block
+under ``jax.checkpoint``: the force backward then keeps one block's edge
+activations at a time.
 
 Layer scopes ``eqv2.edge``, ``eqv2.embed``, ``eqv2.norm``,
 ``eqv2.attn_conv`` (rotations and SO(2) convolutions), ``eqv2.attn_softmax``,
@@ -104,7 +106,8 @@ class EquiformerV2:
         self._grid = (np.float32(to_grid.T), np.float32(project.T))
         self._grid_red = (np.float32(to_grid[self.rows].T),
                           np.float32(project[:, self.rows].T))
-        self.selfmix = SelfmixLayer(L=L, channels=cfg.sphere_channels)
+        self.selfmix = SelfmixLayer(L=L, channels=cfg.sphere_channels,
+                                    tp_impl="quad")
         # LN_sh's weight of each l>0 coefficient: 1/(2l+1), over L degrees
         deg = np.concatenate([np.full(2 * l + 1, l) for l in range(1, L + 1)])
         self._balance = np.float32(1.0 / ((2 * deg + 1) * L))

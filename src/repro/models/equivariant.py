@@ -21,6 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.gaunt_ff import EquivariantConfig
+from repro.core import constants as _c
+from repro.core import rep as _rep
 from repro.core.cg import cg_full_tensor_product
 from repro.core.conv import EquivariantConv
 from repro.core.gaunt import expand_degree_weights
@@ -497,6 +499,34 @@ class SegnnNBody:
 # --------------------------------------------------------------------------
 
 
+def _quad_grid_size(L: int) -> tuple[int, int]:
+    """(n_theta, n_phi) of the smallest Gauss-Legendre x uniform-phi grid
+    exact for the Gaunt self-product at degree L projected onto degree L.
+
+    The integrand Y_a Y_b Y_k has total degree 3L: n_theta Gauss-Legendre
+    nodes integrate cos(theta) polynomials to degree 2*n_theta - 1, and
+    n_phi uniform points integrate e^{i m phi} exactly for |m| < n_phi.
+    At L = 6 that is 10 x 19; one phi point fewer aliases |m| = 18.
+    """
+    return 3 * L // 2 + 1, 3 * L + 1
+
+
+def _selfmix_quad(x, w1, w2, w3, L: int):
+    """P_L[(w1 . x)(s) (w2 . x)(s)] * w3 for x [..., (L+1)^2] by exact S^2
+    quadrature: one sampling matmul against [A*w1 | A*w2], a pointwise
+    product, one projection matmul against P*w3."""
+    nt, nph = _quad_grid_size(L)
+    A = jnp.asarray(np.float32(_c.quad_sample_sh(L, nt, nph)))    # [K, G]
+    P = jnp.asarray(np.float32(_c.quad_project_sh(L, nt, nph)))   # [G, K]
+    _rep.count_conversion("sh_to_quad")
+    _rep.count_conversion("quad_to_sh")
+    G = A.shape[1]
+    sample = jnp.concatenate([A * expand_degree_weights(w1, L)[:, None],
+                              A * expand_degree_weights(w2, L)[:, None]], axis=1)
+    s = x @ sample                                                  # [..., 2G]
+    return (s[..., :G] * s[..., G:]) @ (P * expand_degree_weights(w3, L))
+
+
 @dataclasses.dataclass
 class SelfmixLayer:
     """x -> x + mix(GauntTP(w1 . x, w2 . x)) — the paper's added layer.
@@ -511,11 +541,18 @@ class SelfmixLayer:
     ``shard_spec`` row-shards the layer's product over the mesh's data axes
     on BOTH routes (the resident chain and the batched fallback) — residency
     no longer forces single-device execution.
+
+    ``tp_impl='quad'`` computes the product on the smallest S^2 quadrature
+    exact for it (`_quad_grid_size`): sample, multiply, project, three plain
+    matmuls with w1/w2 folded into the sampling matrix and w3 into the
+    projection — no Fourier conversion and no FFT.  Its matmuls are
+    row-local, so they partition as their input is sharded; they run at the
+    caller's matmul precision against float32 constants.
     """
 
     L: int
     channels: int
-    tp_impl: str = "gaunt"
+    tp_impl: str = "gaunt"  # gaunt | gaunt_fused | gaunt_auto | quad | cg
     resident: bool = True
     shard_spec: object = None
     # chain-backend policy (DESIGN.md §6.4): 'measure' lets the engine's
@@ -540,7 +577,10 @@ class SelfmixLayer:
     def interaction(self, params, x):
         """The layer without its residual: mix(GauntTP(w1 . x, w2 . x))."""
         L = self.L
-        if self.tp_impl == "gaunt" and self.resident:
+        if self.tp_impl == "quad":
+            y = _selfmix_quad(x, params["w1"], params["w2"],
+                              params["w3"][: L + 1], L)
+        elif self.tp_impl == "gaunt" and self.resident:
             from repro.core import engine as _engine
 
             # under 'measure', mirror the real call in the measurement: the

@@ -494,9 +494,9 @@ SERVED_PICKS = {
                                                   "escn_aligned"),
         "chain (1, 1, 1)->1": "tree conversion=half conv=rfft",
     },
-    "eqv2-l6m2-selfmix": {
-        "chain (6, 6)->6": "tree conversion=half conv=rfft",
-    },
+    # the Selfmix runs on its own sphere quadrature (`SelfmixLayer`
+    # tp_impl='quad'): the served step builds no engine plan at all
+    "eqv2-l6m2-selfmix": {},
 }
 
 

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from repro.configs.gaunt_ff import equiformer_v2_tiny, gaunt_mace_ff
+from repro.core import rep
 from repro.core.conv import reduced_rows, so2_conv, wigner_blocks_from_rotmat
 from repro.models.equiformer_v2 import EquiformerV2
 from repro.models.equivariant import MaceGaunt
@@ -76,6 +77,26 @@ def test_matches_the_reference(L, M):
     assert scale > 0
     np.testing.assert_allclose(-np.asarray(g1), f2, atol=3e-5 * scale)
     np.testing.assert_array_equal(np.asarray(g1)[-1], 0.0)
+
+
+def test_selfmix_runs_on_the_sphere_quadrature():
+    """The energy-and-force function's Selfmix takes the quadrature route at
+    the served degree L = 6: its lowered program holds no FFT (the chain
+    engine's route there holds one), and tracing it converts SH to the
+    quadrature grid but never to the Fourier basis (the block scan's body
+    is traced once, not once per block)."""
+    cfg = dataclasses.replace(equiformer_v2_tiny, lmax=6, mmax=2)
+    model = EquiformerV2(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    sp, pos, mask = _cluster(6, 0)
+    nbr, nbr_mask = neighbour_graph(pos, mask, cfg.max_radius, cfg.max_neighbors)
+    force = jax.jit(jax.grad(lambda p: model.energy_graph(
+        params, sp, p, mask, nbr, nbr_mask)))
+    with rep.conversion_stats(fresh=True) as c:
+        text = force.lower(pos).as_text()
+    assert "fft" not in text.lower()
+    assert c["sh_to_fourier"] == 0
+    assert c["sh_to_quad"] >= 1
 
 
 def _rotation(rng):

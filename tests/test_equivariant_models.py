@@ -7,10 +7,12 @@ import numpy as np
 import pytest
 
 from repro.configs.gaunt_ff import EquivariantConfig
-from repro.core import so3
-from repro.core.irreps import num_coeffs
+from repro.core import constants, so3
+from repro.core.gaunt import expand_degree_weights
+from repro.core.irreps import l_array, num_coeffs
 from repro.data import lj_dataset, nbody_dataset
-from repro.models.equivariant import MaceGaunt, SegnnNBody, SelfmixLayer
+from repro.models.equivariant import (MaceGaunt, SegnnNBody, SelfmixLayer,
+                                      _quad_grid_size)
 
 CFG_MACE = EquivariantConfig(name="t", kind="mace", L=1, L_edge=1, channels=8,
                              n_layers=1, n_species=4, nu=2, hidden=16, n_radial=4)
@@ -104,7 +106,7 @@ def test_segnn_trains_nbody(impl):
     assert l1 < l0, (impl, l0, l1)
 
 
-@pytest.mark.parametrize("impl", ["gaunt", "gaunt_fused", "cg"])
+@pytest.mark.parametrize("impl", ["gaunt", "gaunt_fused", "cg", "quad"])
 def test_selfmix_layer_equivariance(impl):
     L, C = 2, 4
     layer = SelfmixLayer(L=L, channels=C, tp_impl=impl)
@@ -128,6 +130,80 @@ def test_selfmix_gaunt_equals_fused():
     x = jnp.asarray(np.random.default_rng(6).normal(size=(3, C, num_coeffs(L))), jnp.float32)
     np.testing.assert_allclose(np.asarray(a(params, x)), np.asarray(b(params, x)),
                                atol=2e-4, rtol=2e-4)
+
+
+def _selfmix_case(L, C=3, n=4):
+    """Random features and non-uniform per-degree weights for a Selfmix."""
+    rng = np.random.default_rng(100 + L)
+    layer = SelfmixLayer(L=L, channels=C)
+    params = layer.init(jax.random.PRNGKey(L))
+    for k, size in (("w1", L + 1), ("w2", L + 1), ("w3", 2 * L + 1)):
+        params[k] = jnp.asarray(1 + 0.3 * rng.normal(size=size), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(n, C, num_coeffs(L))), jnp.float32)
+    return params, x
+
+
+@pytest.mark.parametrize("L", [1, 2, 4, 6])
+def test_selfmix_quad_equals_dense_gaunt(L):
+    """The quadrature route's product is the dense real-Gaunt contraction of
+    (w1 . x) and (w2 . x), scaled by w3 and channel-mixed."""
+    params, x = _selfmix_case(L)
+    e = [np.asarray(expand_degree_weights(params[k][: L + 1], L), np.float64)
+         for k in ("w1", "w2", "w3")]
+    xn = np.asarray(x, np.float64)
+    G = constants.gaunt_dense(L, L, L, "float64")
+    prod = np.einsum("nca,ncb,abk->nck", xn * e[0], xn * e[1], G) * e[2]
+    wl = np.asarray(params["mix"], np.float64)[l_array(L)]   # [K, C, C']
+    want = np.einsum("nck,kcd->ndk", prod, wl)
+    with jax.default_matmul_precision("highest"):
+        got = SelfmixLayer(L=L, channels=3, tp_impl="quad").interaction(
+            params, x)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                               atol=1e-5 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("L", [2, 6])
+def test_selfmix_quad_gradients_match_tree(L):
+    """d/dx, d/dw1, d/dw2 and d/dw3 of the quadrature route equal the
+    spectral chain's."""
+    params, x = _selfmix_case(L)
+    probe = jnp.asarray(np.random.default_rng(L).normal(size=x.shape),
+                        jnp.float32)
+
+    def grads(impl):
+        layer = SelfmixLayer(L=L, channels=3, tp_impl=impl)
+        with jax.default_matmul_precision("highest"):
+            return jax.grad(lambda p, x: jnp.sum(
+                layer.interaction(p, x) * probe), argnums=(0, 1))(params, x)
+
+    (gp_q, gx_q), (gp_t, gx_t) = grads("quad"), grads("gaunt")
+    for k in ("w1", "w2", "w3"):
+        scale = np.abs(np.asarray(gp_t[k])).max()
+        assert scale > 0
+        np.testing.assert_allclose(np.asarray(gp_q[k]), np.asarray(gp_t[k]),
+                                   atol=1e-4 * scale, err_msg=k)
+    np.testing.assert_allclose(np.asarray(gx_q), np.asarray(gx_t),
+                               atol=1e-4 * np.abs(np.asarray(gx_t)).max())
+
+
+def test_selfmix_quad_grid_is_the_smallest_exact():
+    """At L = 6 the 10 x 19 grid reproduces the real-Gaunt tensor to float64
+    rounding; one phi point fewer, or one theta node fewer, does not."""
+    L = 6
+    G = constants.gaunt_dense(L, L, L, "float64")
+
+    def quad_gaunt(nt, nph):
+        A = constants.quad_sample_sh(L, nt, nph)
+        P = constants.quad_project_sh(L, nt, nph)
+        return np.einsum("ag,bg,gk->abk", A, A, P)
+
+    def rel(nt, nph):
+        return np.abs(quad_gaunt(nt, nph) - G).max() / np.abs(G).max()
+
+    assert _quad_grid_size(L) == (10, 19)
+    assert rel(10, 19) < 1e-12
+    assert rel(10, 18) > 1e-2
+    assert rel(9, 19) > 1e-2
 
 
 def test_no_duplicate_random_init_leaves():
